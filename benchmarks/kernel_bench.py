@@ -45,7 +45,6 @@ from __future__ import annotations
 import argparse
 import functools
 import json
-import os
 import pathlib
 import sys
 import time
@@ -159,7 +158,7 @@ def bench_network(net: str, layers, *, m_cap, max_layers, iters,
         f_seed = jax.jit(lambda a, v, i: ops.balanced_spmm(
             a, v, i, n_in=n, impl="xla_gather"))
         f_tiled = jax.jit(lambda a, v, i: ops.balanced_spmm(
-            a, v, i, n_in=n, impl="pallas" if _PALLAS_COMPILED else "xla"))
+            a, v, i, n_in=n, impl=_compiled_impl()))
         t_seed = timeit(f_seed, x, sp.values, sp.indices, iters=iters)
         t_tiled = timeit(f_tiled, x, sp.values, sp.indices, iters=iters)
 
@@ -243,7 +242,7 @@ def bench_decode(shapes, *, iters) -> dict:
         f_seed = jax.jit(lambda a, v, i, n=n: ops.balanced_spmm(
             a, v, i, n_in=n, impl="xla_gather"))
         f_dec = jax.jit(lambda a, v, i, n=n: ops.balanced_spmm(
-            a, v, i, n_in=n, impl="pallas" if _PALLAS_COMPILED else "xla"))
+            a, v, i, n_in=n, impl=_compiled_impl()))
         t_scat = timeit(f_scat, x, sp.values, sp.indices, iters=iters)
         t_seed = timeit(f_seed, x, sp.values, sp.indices, iters=iters)
         t_dec = timeit(f_dec, x, sp.values, sp.indices, iters=iters)
@@ -304,7 +303,7 @@ def bench_quant(shapes, *, iters, interp_m) -> dict:
     not the pre-quant f32 weights — quantization error is the format's
     contract (<= scale/2 per element), not a kernel defect.  Speedup and
     the storage ratio are reported against the f32 tiled row."""
-    impl = "pallas" if _PALLAS_COMPILED else "xla"
+    impl = _compiled_impl()
     rows = []
     for si, (m, n, o) in enumerate(shapes):
         k = max(8, n // 2)
@@ -391,11 +390,12 @@ def bench_dram_model() -> dict:
     return {"deployment": dep.name, "scope": "adc", "networks": nets}
 
 
-# The main timing column compares real compiled code: on TPU
-# (REPRO_PALLAS_INTERPRET=0) that is the Mosaic-compiled tiled kernel; on
-# CPU it is the tiled path's XLA fallback (interpret mode is an emulator —
-# it gets its own reduced-shape columns + parity check below).
-_PALLAS_COMPILED = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "0"
+def _compiled_impl() -> str:
+    """The impl the main timing columns run: real compiled code only — the
+    Mosaic-compiled tiled kernel on a TPU backend, the tiled path's XLA
+    fallback elsewhere (interpret mode is an emulator; it gets its own
+    reduced-shape columns + parity check)."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def main(argv=None):
@@ -471,4 +471,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

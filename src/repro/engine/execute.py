@@ -117,7 +117,7 @@ def next_impl(impl: str) -> str | None:
 
 
 def _tiled_to_flat_stacked(w: TiledBalanced):
-    """`tiled_to_flat` over any leading stacked axes ([*lead, O, NB, KB]):
+    """`tiled_to_flat` over any leading stacked axes ([*lead, NB, O, KB]):
     lead axes fold into the row axis (every row carries the same K under
     the balance invariant), decode flat, restack.  Packed encodings pass
     their (lead-broadcast, identical per slice) perm through so the flat
@@ -127,16 +127,18 @@ def _tiled_to_flat_stacked(w: TiledBalanced):
         # no tile-local scale slot to carry them
         w = dequantize_tiled(w)
     lead = w.values.shape[:-3]
+    nb, o = w.indices.shape[-3], w.indices.shape[-2]
     perm = w.perm
     if perm is not None and perm.ndim > 1:
         perm = perm.reshape(-1, perm.shape[-1])[0]
-    flat = TiledBalanced(w.values.reshape(-1, *w.values.shape[-2:]),
-                         w.indices.reshape(-1, *w.indices.shape[-2:]),
-                         w.counts.reshape(-1, w.counts.shape[-1]),
+
+    def fold(a):                        # [*lead, NB, O, ...] -> [NB, g*O, ...]
+        a = a.reshape(-1, nb, o, *a.shape[len(lead) + 2:])
+        return jnp.moveaxis(a, 0, 1).reshape(nb, -1, *a.shape[3:])
+    flat = TiledBalanced(fold(w.values), fold(w.indices), fold(w.counts),
                          n_in=w.n_in, bn=w.bn, perm=perm)
     vals, idx = tiled_to_flat(flat)
     k = vals.shape[-1]
-    o = w.values.shape[-3]
     return (vals.reshape(*lead, o, k), idx.reshape(*lead, o, k))
 
 
@@ -194,6 +196,22 @@ def demote_layer(lp: LayerPlan, *, to_impl: str | None = None,
                      weights=weights)
 
 
+def _per_shard(fn, x: Array, lp: LayerPlan) -> Array:
+    """Run ``fn(x, weights)`` on every device's shard of a sharded plan's
+    output channels (`plan.shard_plan`): XLA cannot partition a Mosaic
+    kernel, so the call goes through ``shard_map``.  ``x`` enters
+    replicated; the output keeps the weights' output-channel sharding."""
+    from jax.sharding import PartitionSpec as P
+
+    from .plan import _layer_weight_specs
+    mesh = lp.spec.mesh
+    wspec = _layer_weight_specs(lp, mesh)
+    o_axes = wspec.values[-2]                       # [NB, O, KB]: O axis
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(), wspec),
+                         out_specs=P(*([None] * (x.ndim - 1)), o_axes),
+                         check_vma=False)(x, lp.weights)
+
+
 def apply_fc(x: Array, lp: LayerPlan) -> Array:
     """``y = x @ W.T`` for one planned linear layer.
 
@@ -229,8 +247,12 @@ def apply_fc(x: Array, lp: LayerPlan) -> Array:
         blk = spec.blocks_decode if skinny and spec.blocks_decode \
             else spec.blocks
         bm = min(blk.bm, max(8, kernel_ops.bucket_m(m)))
-        y = kernel_ops.tiled_spmm(x, lp.weights, block_m=bm,
-                                  block_o=blk.bo, impl=spec.impl)
+
+        def run(xx, w):
+            return kernel_ops.tiled_spmm(xx, w, block_m=bm, block_o=blk.bo,
+                                         impl=spec.impl)
+        y = run(x, lp.weights) if spec.mesh is None or spec.impl != "pallas" \
+            else _per_shard(run, x, lp)
     else:
         sp = lp.weights
         y = kernel_ops.balanced_spmm(x, sp.values, sp.indices,

@@ -166,17 +166,17 @@ def _check_tiled(spec, w: TiledBalanced, add) -> None:
     if quant != spec.quant:
         add("quant", f"encoding quant={quant!r} != spec.quant="
             f"{spec.quant!r}")
-    # indices always carry the logical [.., O, NB, KB] geometry; int4
+    # indices always carry the logical [.., NB, O, KB] geometry; int4
     # values pack two nibbles per byte, so their last axis is ceil(KB/2)
-    nb, kb = idx.shape[-2], idx.shape[-1]
+    nb, kb = idx.shape[-3], idx.shape[-1]
     want_kb = -(-kb // 2) if quant == "int4" else kb
     if idx.shape[:-1] != vals.shape[:-1] or vals.shape[-1] != want_kb \
             or cnt.shape != idx.shape[:-1]:
         add("shape", f"values {vals.shape} / indices {idx.shape} / "
             f"counts {cnt.shape} disagree (quant={quant})")
         return
-    if idx.shape[-3] != spec.n_out:
-        add("shape", f"O={idx.shape[-3]} != spec.n_out={spec.n_out}")
+    if idx.shape[-2] != spec.n_out:
+        add("shape", f"O={idx.shape[-2]} != spec.n_out={spec.n_out}")
     if w.n_in != spec.n_in:
         add("shape", f"n_in={w.n_in} != spec.n_in={spec.n_in}")
     if nb * w.bn < w.n_in:
@@ -228,7 +228,7 @@ def _check_tiled(spec, w: TiledBalanced, add) -> None:
         add("count_capacity", f"counts span [{cnt.min()}, {cnt.max()}], "
             f"capacity KB={kb}")
         return
-    totals = cnt.reshape(-1, nb).sum(axis=1)
+    totals = np.swapaxes(cnt, -1, -2).reshape(-1, nb).sum(axis=1)
     if totals.size and not (totals == totals[0]).all():
         add("balance", f"per-row NZE totals span [{totals.min()}, "
             f"{totals.max()}] — the equal-NZE invariant is broken")
@@ -236,9 +236,9 @@ def _check_tiled(spec, w: TiledBalanced, add) -> None:
         add("balance", f"per-row NZE total {int(totals[0])} != spec.k="
             f"{spec.k}")
     # valid slots within one block must index distinct columns
-    rows = idx.reshape(-1, nb, kb)
-    valid = np.arange(kb)[None, None, :] < cnt.reshape(-1, nb)[..., None]
-    probe = np.where(valid, rows, w.bn + np.arange(kb)[None, None, :])
+    rows = idx.reshape(-1, kb)
+    valid = np.arange(kb)[None, :] < cnt.reshape(-1)[:, None]
+    probe = np.where(valid, rows, w.bn + np.arange(kb)[None, :])
     srt = np.sort(probe, axis=-1)
     dup = (srt[..., 1:] == srt[..., :-1]) & (srt[..., 1:] < w.bn)
     if dup.any():

@@ -160,6 +160,10 @@ class PlanSpec:
                                     # stored byte counts the execute STATS
                                     # counters must reproduce (None on
                                     # pre-cost plans, e.g. plan_from_balanced)
+    mesh: Any = None                # the mesh `shard_plan` spread the
+                                    # encoded leaves over (None: one
+                                    # device); execute then runs the
+                                    # kernel per shard
 
     @property
     def is_sparse(self) -> bool:
@@ -204,7 +208,7 @@ class LayerPlan:
         w = self.weights
         if isinstance(w, TiledBalanced):
             lead = w.values.shape[:-3]
-            if lead:                    # stacked [*lead, O, NB, KB]
+            if lead:                    # stacked [*lead, NB, O, KB]
                 vf = w.values.reshape(-1, *w.values.shape[-3:])
                 jf = w.indices.reshape(-1, *w.indices.shape[-3:])
                 cf = w.counts.reshape(-1, *w.counts.shape[-2:])
@@ -390,12 +394,12 @@ jax.tree_util.register_pytree_node(
 def default_impl(*, balanced: bool, w_sparsity: float,
                  ifm_sparsity: float = 0.0) -> str:
     """dense below the §VI-F thresholds or for unbalanced patterns; else the
-    Pallas kernel when it compiles (real TPU), the XLA densify+dot fallback
-    when Pallas would run interpreted (CPU containers)."""
+    Pallas kernel where it compiles (a TPU backend), the XLA densify+dot
+    fallback elsewhere (Pallas would only run interpreted)."""
     spec = SparseLinearSpec(w_sparsity=w_sparsity, ifm_sparsity=ifm_sparsity)
     if not balanced or not spec.use_sparse:
         return "dense"
-    return "xla" if kernel_ops._INTERPRET else "pallas"
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -452,7 +456,7 @@ def _format_bits_of(weights: Any, *, elem_bits: int,
     """Per-dispatch format-level bits of an encoded weights pytree (the
     scanned leading axis divides out; expert axes stay in the dispatch)."""
     if isinstance(weights, TiledBalanced):
-        o, nb, kb = weights.indices.shape[-3:]
+        nb, o, kb = weights.indices.shape[-3:]
         g = int(np.prod(weights.indices.shape[:-3])) if \
             weights.indices.ndim > 3 else 1
         per = _cost.tiled_format_bits(o, nb, kb, weights.bn,
@@ -517,7 +521,10 @@ def _maybe_pack(idx: np.ndarray, vals, pattern2: np.ndarray, n_in: int,
     perm = tile_format.pack_columns(pattern2, bn)
     inv = tile_format.invert_perm(perm)
     pidx = inv[idx]
-    order = np.argsort(pidx, axis=-1, kind="stable")
+    # packed positions fit 16 bits at every width served here, where the
+    # stable argsort is a radix sort (linear, not n log n)
+    key = pidx.astype(np.uint16) if nb * bn <= 1 << 16 else pidx
+    order = np.argsort(key, axis=-1, kind="stable")
     pidx = np.take_along_axis(pidx, order, axis=-1).astype(np.int32)
     npack = nb * bn
     kb_packed = tile_format.max_block_count(
@@ -940,9 +947,10 @@ def _plan_stacked(nm: str, w: Array, *, sparsity: float, impl: str | None,
         block_k = max(_KB_ROUND, _round_up(
             mask_block_k(masks.reshape(g * n_out, n_in), bn=blk.bn),
             _KB_ROUND))
-        # nonzero positions ascending per row (the from_mask layout)
-        idx = np.sort(np.argsort(~masks, axis=-1, kind="stable")[..., :k],
-                      axis=-1).astype(np.int32)           # [g, O, K]
+        # nonzero positions ascending per row (the from_mask layout): the
+        # k largest-magnitude columns, which `order` already lists
+        idx = np.asarray(jnp.sort(order[..., :k], axis=-1)).astype(
+            np.int32)                                     # [g, O, K]
         vals = jnp.take_along_axis(wt, jnp.asarray(idx), axis=-1)
         if impl_nm == "pallas" or quant != "none":
             n_enc, perm = n_in, None
@@ -954,6 +962,11 @@ def _plan_stacked(nm: str, w: Array, *, sparsity: float, impl: str | None,
                               idx.reshape(g * n_out, k), n_enc,
                               bn=blk.bn, kb=block_k)
             nb = tb.nb
+            # encoded as one [NB, g*O, KB] stack; lead axes go in front
+            def restack(a):
+                a = a.reshape(nb, g, n_out, *a.shape[2:])
+                return jnp.moveaxis(a, 1, 0).reshape(*lead, nb, n_out,
+                                                     *a.shape[3:])
             perm_leaf = None
             if perm is not None:
                 packed = True
@@ -963,9 +976,7 @@ def _plan_stacked(nm: str, w: Array, *, sparsity: float, impl: str | None,
                     np.broadcast_to(perm, (*lead, perm.shape[0]))) if lead
                     else perm)
             weights = TiledBalanced(
-                tb.values.reshape(*lead, n_out, nb, block_k),
-                tb.indices.reshape(*lead, n_out, nb, block_k),
-                tb.counts.reshape(*lead, n_out, nb),
+                restack(tb.values), restack(tb.indices), restack(tb.counts),
                 n_in=n_in, bn=blk.bn, perm=perm_leaf)
             if quant != "none":
                 weights = quantize_tiled(weights, quant)
@@ -1188,12 +1199,14 @@ def plan_model(cfg, params: dict, **kwargs) -> ModelPlan:
 def masked_dense_params(params: dict, plan: ModelPlan) -> dict:
     """The masked-dense reference: the same pruned weights as the plan,
     densified back into the params layout ([*lead, n_in, n_out]).
-    Sparse-plan serving must match this numerically."""
+    Sparse-plan serving must match this numerically.  Each leaf takes the
+    dtype of the params leaf it replaces; where the caller already dropped
+    that leaf, the densified plan's own dtype."""
     blocks = dict(params["blocks"])
     for nm, lp in plan.layers.items():
         dense = lp.dense_weights()                        # [*lead, O, N]
-        blocks[nm] = jnp.swapaxes(dense, -1, -2).astype(
-            params["blocks"][nm].dtype)
+        dt = blocks[nm].dtype if nm in blocks else dense.dtype
+        blocks[nm] = jnp.swapaxes(dense, -1, -2).astype(dt)
     out = dict(params)
     out["blocks"] = blocks
     return out
@@ -1210,7 +1223,8 @@ def _layer_weight_specs(lp: LayerPlan, mesh):
     L axis replicated (scan slices it), the expert axis over ``model``
     (expert parallelism is TP over E), and the output-channel axis over the
     FSDP axes (``data``/``pod``) — all divisibility-guarded by
-    `distributed.sharding.logical_spec`.
+    `distributed.sharding.logical_spec`.  Tiled leaves are block-major
+    ([.., NB, O, KB]): the NB axis stays whole, O shards.
     """
     from ..distributed import sharding as shd
     w = lp.weights
@@ -1224,7 +1238,7 @@ def _layer_weight_specs(lp: LayerPlan, mesh):
 
     if isinstance(w, TiledBalanced):
         lead = w.values.ndim - 3
-        vplan = lead_plan(lead) + [fsdp, None, None]
+        vplan = lead_plan(lead) + [None, fsdp, None]
         perm_spec = None
         if w.perm is not None:
             # every device permutes the full input row: replicated
@@ -1232,15 +1246,15 @@ def _layer_weight_specs(lp: LayerPlan, mesh):
                 mesh, w.perm.shape, lead_plan(w.perm.ndim - 1) + [None])
         scales_spec = None
         if w.scales is not None:
-            # scales shard exactly like counts ([.., O, NB]): per-block
+            # scales shard exactly like counts ([.., NB, O]): per-block
             # metadata rides with its output-channel shard
             scales_spec = shd.logical_spec(mesh, w.scales.shape,
-                                           lead_plan(lead) + [fsdp, None])
+                                           lead_plan(lead) + [None, fsdp])
         return TiledBalanced(
             shd.logical_spec(mesh, w.values.shape, vplan),
             shd.logical_spec(mesh, w.indices.shape, vplan),
             shd.logical_spec(mesh, w.counts.shape,
-                             lead_plan(lead) + [fsdp, None]),
+                             lead_plan(lead) + [None, fsdp]),
             n_in=w.n_in, bn=w.bn, perm=perm_spec,
             scales=scales_spec, quant=w.quant)
     if isinstance(w, BalancedSparse):
@@ -1274,10 +1288,17 @@ def plan_specs(plan: ModelPlan, mesh) -> ModelPlan:
 
 def shard_plan(plan: ModelPlan, mesh) -> ModelPlan:
     """device_put the plan onto its `plan_specs` shardings (FSDP-style
-    distribution of the encoded values/indices/counts over the mesh)."""
+    distribution of the encoded values/indices/counts over the mesh) and
+    record the mesh in every spec: a Mosaic kernel cannot be partitioned
+    automatically, so `engine.execute` runs it per shard."""
     from ..distributed import sharding as shd
-    return jax.device_put(plan, shd.tree_shardings(mesh,
-                                                   plan_specs(plan, mesh)))
+    placed = jax.device_put(plan, shd.tree_shardings(mesh,
+                                                     plan_specs(plan, mesh)))
+    return ModelPlan(
+        layers={nm: LayerPlan(spec=dataclasses.replace(lp.spec, mesh=mesh),
+                              weights=lp.weights)
+                for nm, lp in placed.layers.items()},
+        meta=placed.meta)
 
 
 __all__ = ["LayerPlan", "ModelPlan", "PlanSpec", "balanced_mask_k",

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ops
+from .balanced_spmm import interpret_mode
 from .tile_format import QUANT_MODES, encode_tiled, max_block_count, \
     quantize_tiled
 
@@ -308,7 +309,7 @@ def sweep_blocks(m: int, o: int, n: int, k: int, *, itemsize: int = 4,
     base = {"backend": jax.default_backend(), "impl": impl,
             "m": m, "o": o, "n": n, "k": k, "itemsize": itemsize,
             "dtype": jnp.dtype(dtype).name, "quant": quant,
-            "jax": jax.__version__, "interpret": ops._INTERPRET}
+            "jax": jax.__version__, "interpret": interpret_mode()}
     if impl not in TUNABLE_IMPLS:
         record = dict(base, source="static",
                       note=f"impl={impl} takes no block parameters",

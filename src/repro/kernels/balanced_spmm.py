@@ -2,24 +2,35 @@
 
 W is the *tile-local* balanced format (`tile_format.TiledBalanced`): each
 output row's nonzeros are pre-partitioned by ``bn``-wide column blocks of the
-input dimension, with block-local indices.  Load balance is what makes the
-kernel possible with static shapes — balanced pruning pins the per-row total
-K and concentrates per-block counts near K/NB, so every grid step does the
-same amount of decode work with no per-row control flow (the TPU-native
-restatement of the paper's equal-NZE-per-PE-column invariant, DESIGN.md §3).
+input dimension, with block-local indices, stored block-major
+``[NB, O, KB]``.  Load balance is what makes the kernel possible with static
+shapes — balanced pruning pins the per-row total K and concentrates
+per-block counts near K/NB, so every grid step does the same amount of
+decode work with no per-row control flow (the TPU-native restatement of the
+paper's equal-NZE-per-PE-column invariant, DESIGN.md §3).
 
-Grid ``(M/bm, O/bo, NB)``: each step scatter-decodes one weight block
-``(values[bo, KB], local_idx[bo, KB]) -> w_tile[bo, bn]`` in VMEM — padded
-slots carry value 0 / index 0, so the scatter needs no masking — then
-accumulates a rank-2 ``jnp.dot(x_tile[bm, bn], w_tile.T)`` on the MXU.  This
-is the column-combining move (Kung et al.): sparse columns packed into dense
-tiles the array consumes at full utilization.  The previous kernel gathered a
-rank-3 ``[bm, bo, bk]`` buffer (8 MiB VMEM at defaults) and reduced it with a
-VPU einsum over an ``nsteps`` serial fori_loop; both are gone.
+Grid ``(M/bm, O/bo, NB)``: each step DMAs one weight block
+``values/indices[nb, o-block] -> [bo, KB]`` (block ``(1, bo, KB)``: the last
+two dims are a sublane multiple and the whole slot axis, which is what
+Mosaic accepts), decodes it to a dense ``[bo, bn]`` f32 tile in VMEM, and
+accumulates ``x_tile[bm, bn] @ w_tile.T`` on the MXU.  This is the
+column-combining move (Kung et al.): sparse columns packed into dense tiles
+the array consumes at full utilization.
+
+The decode is an unrolled compare-select over the KB slots — slot ``k``
+adds ``where(col == idx[:, k], val[:, k], 0)`` to the tile.  Mosaic has no
+in-kernel scatter and no dynamic lane slicing, so this is the formulation
+that lowers; it costs ``KB`` VPU passes over the tile per grid step.
+Padded slots carry value 0 / index 0 and real indices are distinct within
+a block, so the decode is exact with no count masking.
 
 VMEM per step (f32): bm*bn (x) + bo*KB*2 (vals+idx) + bo*bn (decoded tile)
-+ bm*bo (acc) — at bm=bo=bn=128, KB=64: ~0.26 MiB vs the old 8 MiB.
-`ops.choose_blocks` picks bm/bo/bn from shapes and a VMEM budget.
++ bm*bo (acc) — at bm=bo=bn=128, KB=64: ~0.26 MiB.  `ops.choose_blocks`
+picks bm/bo/bn from shapes and a VMEM budget.
+
+``interpret=None`` (the default everywhere) runs the Pallas interpreter on
+a CPU backend and the compiled Mosaic kernel anywhere else; the backend is
+read at call time (`interpret_mode`), never at import.
 """
 from __future__ import annotations
 
@@ -34,30 +45,14 @@ from .tile_format import TiledBalanced
 Array = jax.Array
 
 
-def _decode_tile(vals, idx, scales, quant: str, bn: int):
-    """Scatter-decode one weight block to a dense ``[bo, bn]`` VMEM tile.
-
-    ``vals`` is the stored encoding (f32/bf16, int8, or nibble-packed
-    uint8), ``idx`` the block-local [bo, KB] indices, ``scales`` the
-    per-row [bo, 1] block scales (None when quant == "none").  Quantized
-    values dequantize *here*, in VMEM, immediately before the scatter that
-    feeds the MXU dot — DRAM and the block pipeline only ever move the
-    narrow words.  Must reconstruct exactly like
-    `tile_format.dequantize_values` (the parity reference).
-    """
-    bo, kb = idx.shape
-    if quant == "int4":
-        lo = vals & 0xF
-        hi = vals >> 4
-        q = jnp.stack([lo, hi], axis=-1).reshape(
-            bo, vals.shape[1] * 2).astype(jnp.int8)
-        v = (((q ^ 8) - 8)[:, :kb]).astype(jnp.float32) * scales
-    elif quant == "int8":
-        v = vals.astype(jnp.float32) * scales
-    else:
-        v = vals.astype(jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
-    return jnp.zeros((bo, bn), jnp.float32).at[rows, idx].add(v)
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Resolve a kernel's ``interpret`` flag: an explicit bool wins, None
+    means "interpret exactly when the default backend is the CPU" (Pallas
+    TPU kernels cannot compile there).  Read per call, so a process that
+    sees a TPU never runs a kernel in the interpreter."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() == "cpu"
 
 
 def _packed_kb(kb: int, quant: str) -> int:
@@ -65,203 +60,129 @@ def _packed_kb(kb: int, quant: str) -> int:
     return -(-kb // 2) if quant == "int4" else kb
 
 
-def _kernel(x_ref, v_ref, i_ref, o_ref):
-    """One (m, o, nb) step: o_ref += x[bm, bn] @ decode(W block)[bn, bo]."""
-    nb = pl.program_id(2)
+def _decode_tile(vals, idx, scales, quant: str, bn: int):
+    """Decode one weight block to a dense ``[bo, bn]`` f32 VMEM tile.
+
+    ``vals`` is the stored encoding (f32/bf16, int8, or nibble-packed
+    uint8), ``idx`` the block-local [bo, KB] indices, ``scales`` the
+    per-row [bo, 1] block scales (None when quant == "none").  Quantized
+    values dequantize *here*, in VMEM: each tile element receives at most
+    one slot's ``q``, so scaling the decoded tile once is exactly
+    `tile_format.dequantize_values` (the parity reference) per element.
+    """
+    bo, kb = idx.shape
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bo, bn), 1)
+    words = vals.astype(jnp.int32 if quant == "int4" else jnp.float32)
+    w = jnp.zeros((bo, bn), jnp.float32)
+    for k in range(kb):
+        if quant == "int4":
+            # slot k is nibble k % 2 of byte k // 2; (n ^ 8) - 8 sign-extends
+            nib = (words[:, k // 2:k // 2 + 1] >> (4 * (k % 2))) & 0xF
+            v = ((nib ^ 8) - 8).astype(jnp.float32)
+        else:
+            v = words[:, k:k + 1]
+        w = w + jnp.where(cols == idx[:, k:k + 1], v, 0.0)
+    if scales is not None:
+        w = w * scales
+    return w
+
+
+def _kernel(x_ref, v_ref, i_ref, *rest, quant: str, nb_axis: int):
+    """One grid step: o_ref += x[bm, bn] @ decode(W block)[bn, bo].
+
+    Shared by the wide, skinny and batched-expert grids; ``nb_axis`` names
+    the grid axis that walks the column blocks (the reduction, innermost).
+    Every ref carries unit leading block dims, squeezed here."""
+    o_ref = rest[-1]
+    s_ref = rest[0] if len(rest) == 2 else None
+    nb = pl.program_id(nb_axis)
 
     @pl.when(nb == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]                                    # [bm, bn]
-    vals = v_ref[...].reshape(v_ref.shape[0], v_ref.shape[2])   # [bo, KB]
-    idx = i_ref[...].reshape(i_ref.shape[0], i_ref.shape[2])    # [bo, KB]
-    bn = x.shape[1]
-    bo = vals.shape[0]
-    # scatter-decode the block to a dense [bo, bn] VMEM tile; zero-padded
-    # slots (val 0, idx 0) are no-ops under add, duplicates cannot occur
-    # among real entries (indices are distinct within a block).
-    rows = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
-    w_tile = jnp.zeros((bo, bn), jnp.float32).at[rows, idx].add(
-        vals.astype(jnp.float32))
-    o_ref[...] += jnp.dot(x, w_tile.T, preferred_element_type=jnp.float32)
+    x = x_ref[...].reshape(x_ref.shape[-2:])                    # [bm, bn]
+    vals = v_ref[...].reshape(v_ref.shape[-2:])                 # [bo, KBp]
+    idx = i_ref[...].reshape(i_ref.shape[-2:])                  # [bo, KB]
+    scales = None if s_ref is None else s_ref[...].reshape(s_ref.shape[-2:])
+    w_tile = _decode_tile(vals, idx, scales, quant, x.shape[1])
+    # f32 activations get an f32 product (bf16 ones are one exact MXU pass)
+    precision = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    acc = jax.lax.dot_general(x, w_tile.astype(x.dtype),
+                              (((1,), (1,)), ((), ())), precision=precision,
+                              preferred_element_type=jnp.float32)
+    o_ref[...] += acc.reshape(o_ref.shape)
 
 
-def _kernel_q(x_ref, v_ref, i_ref, s_ref, o_ref, *, quant: str):
-    """Quantized twin of `_kernel`: same grid step plus a [bo, 1] scales
-    tile; narrow values dequantize in VMEM inside `_decode_tile`."""
-    nb = pl.program_id(2)
-
-    @pl.when(nb == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    x = x_ref[...]                                              # [bm, bn]
-    vals = v_ref[...].reshape(v_ref.shape[0], v_ref.shape[2])   # [bo, KBp]
-    idx = i_ref[...].reshape(i_ref.shape[0], i_ref.shape[2])    # [bo, KB]
-    w_tile = _decode_tile(vals, idx, s_ref[...], quant, x.shape[1])
-    o_ref[...] += jnp.dot(x, w_tile.T, preferred_element_type=jnp.float32)
+def _weight_operands(values, indices, scales, quant: str):
+    """Kernel operands: values, indices, and the scales as a trailing-unit
+    column (``[.., NB, O, 1]``: a ``(bo, 1)`` block is whole in its last
+    dim, where a ``[.., NB, O]`` row block would not be)."""
+    if quant == "none":
+        return (values, indices)
+    return (values, indices, scales[..., None])
 
 
 def tiled_balanced_spmm_pallas(x: Array, tb: TiledBalanced, *, bm: int = 128,
                                bo: int = 128,
-                               interpret: bool = True) -> Array:
+                               interpret: bool | None = None) -> Array:
     """Raw pallas_call; shapes must already be tile-aligned (see ops.py).
 
-    x: [M, NB*bn]; tb.values/indices: [O, NB, KB] with M % bm == O % bo == 0
-    (int4 values are nibble-packed [O, NB, KB/2]).  Returns f32 [M, O]
-    (accumulator dtype; caller casts).
+    x: [M, NB*bn]; tb.values/indices: [NB, O, KB] with M % bm == O % bo == 0
+    (int4 values are nibble-packed [NB, O, KB/2]; scales [NB, O] when
+    quantized).  Returns f32 [M, O] (accumulator dtype; caller casts).
     """
     m, n = x.shape
-    o, nb, kb = tb.indices.shape
+    nb, o, kb = tb.indices.shape
     bn = tb.bn
     assert n == nb * bn and m % bm == 0 and o % bo == 0, (x.shape, tb.indices.shape, bm, bo, bn)
-    grid = (m // bm, o // bo, nb)
-    if tb.quant == "none":
-        return pl.pallas_call(
-            _kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bm, bn), lambda i, j, b: (i, b)),  # x col-block
-                pl.BlockSpec((bo, 1, kb), lambda i, j, b: (j, b, 0)),  # values
-                pl.BlockSpec((bo, 1, kb), lambda i, j, b: (j, b, 0)),  # idx
-            ],
-            out_specs=pl.BlockSpec((bm, bo), lambda i, j, b: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((m, o), jnp.float32),
-            interpret=interpret,
-        )(x, tb.values, tb.indices)
     kbp = _packed_kb(kb, tb.quant)
+    in_specs = [
+        pl.BlockSpec((bm, bn), lambda i, j, b: (i, b)),          # x col-block
+        pl.BlockSpec((1, bo, kbp), lambda i, j, b: (b, j, 0)),   # values
+        pl.BlockSpec((1, bo, kb), lambda i, j, b: (b, j, 0)),    # local idx
+    ]
+    if tb.quant != "none":
+        in_specs.append(pl.BlockSpec((1, bo, 1), lambda i, j, b: (b, j, 0)))
     return pl.pallas_call(
-        functools.partial(_kernel_q, quant=tb.quant),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, b: (i, b)),      # x col-block
-            pl.BlockSpec((bo, 1, kbp), lambda i, j, b: (j, b, 0)),  # q values
-            pl.BlockSpec((bo, 1, kb), lambda i, j, b: (j, b, 0)),   # local idx
-            pl.BlockSpec((bo, 1), lambda i, j, b: (j, b)),          # scales
-        ],
+        functools.partial(_kernel, quant=tb.quant, nb_axis=2),
+        grid=(m // bm, o // bo, nb),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bo), lambda i, j, b: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, o), jnp.float32),
-        interpret=interpret,
-    )(x, tb.values, tb.indices, tb.scales)
-
-
-def _kernel_skinny(x_ref, v_ref, i_ref, o_ref):
-    """One (o, nb) step for decode-shaped M: the whole (padded, <= 8-row)
-    activation block stays resident across the grid — no M axis, no x
-    re-tiling per step, and the [bm, bo] accumulator costs almost nothing."""
-    nb = pl.program_id(1)
-
-    @pl.when(nb == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    x = x_ref[...]                                              # [m, bn]
-    vals = v_ref[...].reshape(v_ref.shape[0], v_ref.shape[2])   # [bo, KB]
-    idx = i_ref[...].reshape(i_ref.shape[0], i_ref.shape[2])    # [bo, KB]
-    bn = x.shape[1]
-    bo = vals.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
-    w_tile = jnp.zeros((bo, bn), jnp.float32).at[rows, idx].add(
-        vals.astype(jnp.float32))
-    o_ref[...] += jnp.dot(x, w_tile.T, preferred_element_type=jnp.float32)
-
-
-def _kernel_skinny_q(x_ref, v_ref, i_ref, s_ref, o_ref, *, quant: str):
-    """Quantized twin of `_kernel_skinny` (scales tile + in-VMEM dequant)."""
-    nb = pl.program_id(1)
-
-    @pl.when(nb == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    x = x_ref[...]                                              # [m, bn]
-    vals = v_ref[...].reshape(v_ref.shape[0], v_ref.shape[2])   # [bo, KBp]
-    idx = i_ref[...].reshape(i_ref.shape[0], i_ref.shape[2])    # [bo, KB]
-    w_tile = _decode_tile(vals, idx, s_ref[...], quant, x.shape[1])
-    o_ref[...] += jnp.dot(x, w_tile.T, preferred_element_type=jnp.float32)
+        interpret=interpret_mode(interpret),
+    )(x, *_weight_operands(tb.values, tb.indices, tb.scales, tb.quant))
 
 
 def tiled_balanced_spmm_skinny_pallas(x: Array, tb: TiledBalanced, *,
                                       bo: int = 128,
-                                      interpret: bool = True) -> Array:
+                                      interpret: bool | None = None
+                                      ) -> Array:
     """Decode-specialized variant of `tiled_balanced_spmm_pallas` for skinny
     M (a decode step's whole batch, padded to the 8-row sublane).  Grid
-    ``(O/bo, NB)`` — bm is pinned to the decode shape, so the skinny M never
-    pays a full [128, bn] x-tile load per step.
+    ``(O/bo, NB)`` — the whole activation block stays resident, so the
+    skinny M never pays a full [128, bn] x-tile load per step.
     """
     m, n = x.shape
-    o, nb, kb = tb.indices.shape
+    nb, o, kb = tb.indices.shape
     bn = tb.bn
     assert n == nb * bn and o % bo == 0 and m <= 8, (x.shape, tb.indices.shape, bo, bn)
-    grid = (o // bo, nb)
-    if tb.quant == "none":
-        return pl.pallas_call(
-            _kernel_skinny,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((m, bn), lambda j, b: (0, b)),      # x col-block
-                pl.BlockSpec((bo, 1, kb), lambda j, b: (j, b, 0)),   # values
-                pl.BlockSpec((bo, 1, kb), lambda j, b: (j, b, 0)),   # idx
-            ],
-            out_specs=pl.BlockSpec((m, bo), lambda j, b: (0, j)),
-            out_shape=jax.ShapeDtypeStruct((m, o), jnp.float32),
-            interpret=interpret,
-        )(x, tb.values, tb.indices)
     kbp = _packed_kb(kb, tb.quant)
+    in_specs = [
+        pl.BlockSpec((m, bn), lambda j, b: (0, b)),              # x col-block
+        pl.BlockSpec((1, bo, kbp), lambda j, b: (b, j, 0)),      # values
+        pl.BlockSpec((1, bo, kb), lambda j, b: (b, j, 0)),       # local idx
+    ]
+    if tb.quant != "none":
+        in_specs.append(pl.BlockSpec((1, bo, 1), lambda j, b: (b, j, 0)))
     return pl.pallas_call(
-        functools.partial(_kernel_skinny_q, quant=tb.quant),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((m, bn), lambda j, b: (0, b)),          # x col-block
-            pl.BlockSpec((bo, 1, kbp), lambda j, b: (j, b, 0)),  # q values
-            pl.BlockSpec((bo, 1, kb), lambda j, b: (j, b, 0)),   # local idx
-            pl.BlockSpec((bo, 1), lambda j, b: (j, b)),          # scales
-        ],
+        functools.partial(_kernel, quant=tb.quant, nb_axis=1),
+        grid=(o // bo, nb),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((m, bo), lambda j, b: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, o), jnp.float32),
-        interpret=interpret,
-    )(x, tb.values, tb.indices, tb.scales)
-
-
-def _kernel_batched(x_ref, v_ref, i_ref, o_ref):
-    """One (e, m, o, nb) step of the fused expert grid: identical math to
-    `_kernel` on the expert's slice — the expert axis is a grid dimension,
-    not a host-level scan, so all experts trace/compile once and XLA
-    pipelines their steps back-to-back."""
-    nb = pl.program_id(3)
-
-    @pl.when(nb == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    x = x_ref[...].reshape(x_ref.shape[1], x_ref.shape[2])      # [bm, bn]
-    vals = v_ref[...].reshape(v_ref.shape[1], v_ref.shape[3])   # [bo, KB]
-    idx = i_ref[...].reshape(i_ref.shape[1], i_ref.shape[3])    # [bo, KB]
-    bn = x.shape[1]
-    bo = vals.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
-    w_tile = jnp.zeros((bo, bn), jnp.float32).at[rows, idx].add(
-        vals.astype(jnp.float32))
-    acc = jnp.dot(x, w_tile.T, preferred_element_type=jnp.float32)
-    o_ref[...] += acc[None]
-
-
-def _kernel_batched_q(x_ref, v_ref, i_ref, s_ref, o_ref, *, quant: str):
-    """Quantized twin of `_kernel_batched` (per-expert scales tile)."""
-    nb = pl.program_id(3)
-
-    @pl.when(nb == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    x = x_ref[...].reshape(x_ref.shape[1], x_ref.shape[2])      # [bm, bn]
-    vals = v_ref[...].reshape(v_ref.shape[1], v_ref.shape[3])   # [bo, KBp]
-    idx = i_ref[...].reshape(i_ref.shape[1], i_ref.shape[3])    # [bo, KB]
-    scales = s_ref[...].reshape(s_ref.shape[1], s_ref.shape[2])  # [bo, 1]
-    w_tile = _decode_tile(vals, idx, scales, quant, x.shape[1])
-    acc = jnp.dot(x, w_tile.T, preferred_element_type=jnp.float32)
-    o_ref[...] += acc[None]
+        interpret=interpret_mode(interpret),
+    )(x, *_weight_operands(tb.values, tb.indices, tb.scales, tb.quant))
 
 
 def tiled_balanced_spmm_batched_pallas(x: Array, values: Array,
@@ -269,44 +190,33 @@ def tiled_balanced_spmm_batched_pallas(x: Array, values: Array,
                                        bm: int = 128, bo: int = 128,
                                        scales: Array | None = None,
                                        quant: str = "none",
-                                       interpret: bool = True) -> Array:
+                                       interpret: bool | None = None
+                                       ) -> Array:
     """Fused batched (per-expert) tiled matmul: one grid over all experts.
 
-    x: [E, M, NB*bn]; values/indices: [E, O, NB, KB] with M % bm == 0 and
-    O % bo == 0 (int4 values [E, O, NB, KB/2]; ``scales`` [E, O, NB] when
-    quantized).  Grid ``(E, M/bm, O/bo, NB)`` replaces the per-expert
+    x: [E, M, NB*bn]; values/indices: [E, NB, O, KB] with M % bm == 0 and
+    O % bo == 0 (int4 values [E, NB, O, KB/2]; ``scales`` [E, NB, O] when
+    quantized).  Grid ``(E, M/bm, O/bo, NB)`` replaces a per-expert
     `lax.scan` dispatch (one kernel launch and one trace for the whole MoE
     layer).  Returns f32 [E, M, O].
     """
     e, m, n = x.shape
-    _, o, nb, kb = indices.shape
+    _, nb, o, kb = indices.shape
     assert n == nb * bn and m % bm == 0 and o % bo == 0, (x.shape, indices.shape, bm, bo, bn)
-    grid = (e, m // bm, o // bo, nb)
-    if quant == "none":
-        return pl.pallas_call(
-            _kernel_batched,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bm, bn), lambda g, i, j, b: (g, i, b)),
-                pl.BlockSpec((1, bo, 1, kb), lambda g, i, j, b: (g, j, b, 0)),
-                pl.BlockSpec((1, bo, 1, kb), lambda g, i, j, b: (g, j, b, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bm, bo),
-                                   lambda g, i, j, b: (g, i, j)),
-            out_shape=jax.ShapeDtypeStruct((e, m, o), jnp.float32),
-            interpret=interpret,
-        )(x, values, indices)
     kbp = _packed_kb(kb, quant)
+    in_specs = [
+        pl.BlockSpec((1, bm, bn), lambda g, i, j, b: (g, i, b)),
+        pl.BlockSpec((1, 1, bo, kbp), lambda g, i, j, b: (g, b, j, 0)),
+        pl.BlockSpec((1, 1, bo, kb), lambda g, i, j, b: (g, b, j, 0)),
+    ]
+    if quant != "none":
+        in_specs.append(
+            pl.BlockSpec((1, 1, bo, 1), lambda g, i, j, b: (g, b, j, 0)))
     return pl.pallas_call(
-        functools.partial(_kernel_batched_q, quant=quant),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bm, bn), lambda g, i, j, b: (g, i, b)),
-            pl.BlockSpec((1, bo, 1, kbp), lambda g, i, j, b: (g, j, b, 0)),
-            pl.BlockSpec((1, bo, 1, kb), lambda g, i, j, b: (g, j, b, 0)),
-            pl.BlockSpec((1, bo, 1), lambda g, i, j, b: (g, j, b)),
-        ],
+        functools.partial(_kernel, quant=quant, nb_axis=3),
+        grid=(e, m // bm, o // bo, nb),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bm, bo), lambda g, i, j, b: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, m, o), jnp.float32),
-        interpret=interpret,
-    )(x, values, indices, scales)
+        interpret=interpret_mode(interpret),
+    )(x, *_weight_operands(values, indices, scales, quant))

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .balanced_spmm import interpret_mode
+
 Array = jax.Array
 
 
@@ -50,7 +52,7 @@ def _kernel(x_ref, bmp_ref, pak_ref, off_ref, o_ref):
 
 def bitmap_spmm_pallas(x: Array, bitmap: Array, packed: Array,
                        offsets: Array, *, bm: int = 128, bo: int = 128,
-                       bn: int = 128, interpret: bool = True) -> Array:
+                       bn: int = 128, interpret: bool | None = None) -> Array:
     """Raw pallas_call; tile-aligned shapes (see ops.py for padding).
 
     x: [M, N]; bitmap: [O, N] int8; packed: [O, K] NZE rows (raster order);
@@ -72,7 +74,7 @@ def bitmap_spmm_pallas(x: Array, bitmap: Array, packed: Array,
         ],
         out_specs=pl.BlockSpec((bm, bo), lambda i, j, nb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, o), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, bitmap, packed, offsets)
 
 

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .balanced_spmm import interpret_mode
+
 Array = jax.Array
 
 
@@ -52,7 +54,7 @@ def _kernel(pos_ref, new_ref, cache_ref, out_ref):
 
 
 def kv_cache_update_pallas(cache: Array, new: Array, pos: Array, *,
-                           interpret: bool = True) -> Array:
+                           interpret: bool | None = None) -> Array:
     """cache: [P, S, dh] planes; new: [P, dh]; pos: [P] int32.
 
     Returns the cache with ``new[p]`` written at ``cache[p, pos[p]]`` — one
@@ -70,7 +72,7 @@ def kv_cache_update_pallas(cache: Array, new: Array, pos: Array, *,
         out_specs=pl.BlockSpec((1, s, dh), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((p, s, dh), cache.dtype),
         input_output_aliases={2: 0},
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(pos, new, cache)
     return out
 

@@ -8,7 +8,7 @@ with a per-weight-id cache, a differentiable path (Pallas forward + jnp
 backward via custom_vjp), and XLA fallbacks:
 
 * ``impl="pallas"``     — tile-local decode-and-matmul kernel (MXU-native;
-                          interpret mode on CPU).  Skinny M (<= `SKINNY_M`,
+                          interpreted on a CPU backend).  Skinny M (<= `SKINNY_M`,
                           the decode-step shape) dispatches the decode-
                           specialized kernel variant (grid without an M
                           axis, bm pinned to the padded decode batch).
@@ -30,8 +30,9 @@ Flat-format ``indices`` must be ascending within each row — every encoder
 in this repo guarantees it (`to_balanced_sparse`, the plan builders,
 `tiled_to_flat`) and the searchsorted densify relies on it.
 
-This container is CPU-only, so ``interpret=True`` is the default; on real
-TPU set ``REPRO_PALLAS_INTERPRET=0``.
+Interpret mode is not an option: every kernel call asks the backend
+(`balanced_spmm.interpret_mode`) — the Pallas interpreter on a CPU backend,
+the Mosaic-compiled kernel on a TPU.
 """
 from __future__ import annotations
 
@@ -59,8 +60,6 @@ from .tile_format import (TiledBalanced, dequantize_values, encode_tiled,
 QUANT_WBYTES = {"none": None, "int8": 1.0, "int4": 0.5}
 
 Array = jax.Array
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 # M at or below which the decode-specialized paths dispatch.  8 is the f32
 # sublane and covers every decode shape serving produces: a decode step's
@@ -365,6 +364,14 @@ def _balanced_spmm_xla(x: Array, values: Array, indices: Array,
                    preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+def _pad_rows(a: Array, extra: int, row_axis: int = -2) -> Array:
+    """Zero-pad the output-row axis of a tiled leaf (``-2`` for the
+    [.., NB, O, KB] values/indices, ``-1`` for [.., NB, O] counts/scales)."""
+    pad = [(0, 0)] * a.ndim
+    pad[row_axis] = (0, extra)
+    return jnp.pad(a, pad)
+
+
 def _pad_and_run_tiled(x: Array, tb: TiledBalanced, bm: int, bo: int,
                        skinny: bool = False) -> Array:
     """Pad (M, O, N) to tile multiples, run the kernel, slice back.
@@ -374,7 +381,7 @@ def _pad_and_run_tiled(x: Array, tb: TiledBalanced, bm: int, bo: int,
     if skinny:
         _fault_trip("pallas_decode", bm=bm, bo=bo, bn=tb.bn)
     m = x.shape[0]
-    o = tb.indices.shape[0]
+    o = tb.n_out
     # skinny: M pads to the 8-row sublane regardless of the plan's bm (the
     # decode kernel has no M grid axis, so bm is not a dispatch parameter)
     mp = _round_up(m, 8) if skinny else _round_up(m, bm)
@@ -384,19 +391,16 @@ def _pad_and_run_tiled(x: Array, tb: TiledBalanced, bm: int, bo: int,
         # zero-padded rows decode to all-zero tiles — harmless (a zero
         # scale against all-zero q slots is the valid empty-block encoding)
         tb = TiledBalanced(
-            jnp.pad(tb.values, ((0, op_ - o), (0, 0), (0, 0))),
-            jnp.pad(tb.indices, ((0, op_ - o), (0, 0), (0, 0))),
-            jnp.pad(tb.counts, ((0, op_ - o), (0, 0))),
+            _pad_rows(tb.values, op_ - o), _pad_rows(tb.indices, op_ - o),
+            _pad_rows(tb.counts, op_ - o, row_axis=-1),
             n_in=tb.n_in, bn=tb.bn,
             scales=None if tb.scales is None
-            else jnp.pad(tb.scales, ((0, op_ - o), (0, 0))),
+            else _pad_rows(tb.scales, op_ - o, row_axis=-1),
             quant=tb.quant)
     if skinny:
-        y = tiled_balanced_spmm_skinny_pallas(xp, tb, bo=bo,
-                                              interpret=_INTERPRET)
+        y = tiled_balanced_spmm_skinny_pallas(xp, tb, bo=bo)
     else:
-        y = tiled_balanced_spmm_pallas(xp, tb, bm=bm, bo=bo,
-                                       interpret=_INTERPRET)
+        y = tiled_balanced_spmm_pallas(xp, tb, bm=bm, bo=bo)
     return y[:m, :o].astype(x.dtype)
 
 
@@ -480,23 +484,30 @@ def _tiled_fwd(x, values, indices, counts, n_in, bn, bm, bo, skinny):
     return y, (x, values, indices, counts)
 
 
+def _slot_grads(dw: Array, indices: Array, counts: Array, bn: int) -> Array:
+    """Gather a dense weight cotangent ``dw [O, NB*bn]`` back into the tile
+    slots ``[NB, O, KB]``; padded slots (idx 0 beyond the block count) must
+    not pick up ``dw[.., 0]``."""
+    nb, o, kb = indices.shape
+    cols = jnp.arange(nb)[:, None, None] * bn + indices           # [NB, O, KB]
+    gathered = jnp.take_along_axis(
+        dw, jnp.transpose(cols, (1, 0, 2)).reshape(o, nb * kb), axis=1)
+    gathered = jnp.transpose(gathered.reshape(o, nb, kb), (1, 0, 2))
+    valid = jnp.arange(kb)[None, None, :] < counts[..., None]
+    return jnp.where(valid, gathered, 0.0)
+
+
 def _tiled_bwd(n_in, bn, bm, bo, skinny, res, dy):
-    from .tile_format import tiled_to_dense
     x, values, indices, counts = res
-    o, nb, kb = values.shape
+    nb = values.shape[0]
     w = tiled_to_dense(TiledBalanced(values, indices, counts,
                                      n_in=n_in, bn=bn))           # [O, N]
     dx = jnp.dot(dy, w, preferred_element_type=jnp.float32).astype(x.dtype)
-    # dW[o, n] = sum_m dy[m, o] x[m, n], gathered back into the tile slots;
-    # padded slots (idx 0 beyond the block count) must not pick up dW[.., 0]
+    # dW[o, n] = sum_m dy[m, o] x[m, n], gathered back into the tile slots
     dw = jnp.einsum("mo,mn->on", dy, x,
                     preferred_element_type=jnp.float32)           # [O, N]
     dw = jnp.pad(dw, ((0, 0), (0, nb * bn - n_in)))
-    cols = jnp.arange(nb)[None, :, None] * bn + indices           # [O, NB, KB]
-    gathered = jnp.take_along_axis(dw[:, None, :], cols.reshape(o, 1, -1),
-                                   axis=2).reshape(o, nb, kb)
-    valid = jnp.arange(kb)[None, None, :] < counts[..., None]
-    dvals = jnp.where(valid, gathered, 0.0).astype(values.dtype)
+    dvals = _slot_grads(dw, indices, counts, bn).astype(values.dtype)
     return dx, dvals, None, None
 
 
@@ -504,27 +515,13 @@ _tiled_spmm.defvjp(_tiled_fwd, _tiled_bwd)
 
 
 def _densify_gather_tiled(values, indices, counts, scales, bn, quant):
-    """Gather-only densify of a (perm-free) tiled encoding ->
-    ``[O, NB*bn]`` f32, dequantized — the tiled twin of `_densify_gather`
-    (same searchsorted trick, per block instead of per row; same reason:
-    XLA lowers gathers to vectorized loads where the scatter in
-    `tiled_to_dense` serializes on CPU).  Block-local indices are ascending
-    over each block's live slots (`encode_tiled` preserves the flat
-    format's ascending order); pad slots are re-pointed at the
-    out-of-range sentinel ``bn`` so every searched row is sorted.  The
-    (O, NB) block axes are collapsed before the vmap — one batched
-    searchsorted over O*NB rows lowers to a single fused gather loop,
-    measurably faster than the nested-vmap form at large N."""
-    o, nb, kb = indices.shape
-    vals = dequantize_values(values, scales, quant, kb).reshape(o * nb, kb)
-    valid = jnp.arange(kb, dtype=indices.dtype) < counts[..., None]
-    idx = jnp.where(valid, indices, bn).reshape(o * nb, kb)
-    cols = jnp.arange(bn, dtype=indices.dtype)
-    slot = jax.vmap(lambda row: jnp.searchsorted(row, cols))(idx)
-    slot = jnp.clip(slot, 0, kb - 1)
-    hit = jnp.take_along_axis(idx, slot, axis=-1) == cols
-    out = jnp.where(hit, jnp.take_along_axis(vals, slot, axis=-1), 0.0)
-    return out.reshape(o, nb * bn)
+    """Densify a (perm-free) tiled encoding -> ``[O, NB*bn]``, dequantized
+    (`tile_format.tiled_to_dense` at the padded width: the caller already
+    moved ``x`` into packed space)."""
+    nb = indices.shape[0]
+    return tiled_to_dense(TiledBalanced(values, indices, counts,
+                                        n_in=nb * bn, bn=bn, scales=scales,
+                                        quant=quant))
 
 
 def _tiled_gather_spmm(x, values, indices, scales, bn, quant):
@@ -537,21 +534,18 @@ def _tiled_gather_spmm(x, values, indices, scales, bn, quant):
     block): the scale multiply then costs O(M*O*NB) instead of O(O*SLOTS),
     which at decode M is the difference between matching the f32 gather
     and trailing it by the whole dequant."""
-    o, nb, kb = indices.shape
-    cols = (jnp.arange(nb, dtype=indices.dtype)[None, :, None] * bn
-            + indices).reshape(o, nb * kb)
+    nb, o, kb = indices.shape
+    cols = jnp.arange(nb, dtype=indices.dtype)[:, None, None] * bn + indices
     xp = jnp.pad(x, ((0, 0), (0, nb * bn - x.shape[1])))
-    xg = jnp.take(xp, cols, axis=1)                      # [M, O, NB*KB]
+    xg = jnp.take(xp, cols, axis=1)                      # [M, NB, O, KB]
     if scales is None or quant == "none":
-        vals = dequantize_values(values, scales, quant, kb).reshape(o, -1)
-        return jnp.einsum("mos,os->mo", xg, vals,
+        vals = dequantize_values(values, scales, quant, kb)
+        return jnp.einsum("mnos,nos->mo", xg, vals,
                           preferred_element_type=jnp.float32)
     q = unpack_int4(values, kb) if quant == "int4" else values
-    partial = jnp.einsum("mons,ons->mon",
-                         xg.reshape(x.shape[0], o, nb, kb),
-                         q.astype(jnp.float32),
+    partial = jnp.einsum("mnos,nos->mno", xg, q.astype(jnp.float32),
                          preferred_element_type=jnp.float32)
-    return jnp.einsum("mon,on->mo", partial, scales)
+    return jnp.einsum("mno,no->mo", partial, scales)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
@@ -656,16 +650,13 @@ def tiled_spmm(x: Array, tb: TiledBalanced, *, block_m: int | None = None,
 def _tiled_spmm_batched(x, values, indices, counts, n_in, bn, bm, bo):
     _fault_trip("pallas", bm=bm, bo=bo, bn=bn, batched=True)
     e, m, _ = x.shape
-    o = values.shape[1]
-    nb = values.shape[2]
+    nb, o = values.shape[1], values.shape[2]
     mp, op_ = _round_up(m, bm), _round_up(o, bo)
     xp = jnp.pad(x, ((0, 0), (0, mp - m), (0, nb * bn - x.shape[2])))
     vp, ip = values, indices
     if op_ != o:
-        vp = jnp.pad(values, ((0, 0), (0, op_ - o), (0, 0), (0, 0)))
-        ip = jnp.pad(indices, ((0, 0), (0, op_ - o), (0, 0), (0, 0)))
-    y = tiled_balanced_spmm_batched_pallas(xp, vp, ip, bn=bn, bm=bm, bo=bo,
-                                           interpret=_INTERPRET)
+        vp, ip = _pad_rows(values, op_ - o), _pad_rows(indices, op_ - o)
+    y = tiled_balanced_spmm_batched_pallas(xp, vp, ip, bn=bn, bm=bm, bo=bo)
     return y[:, :m, :o].astype(x.dtype)
 
 
@@ -675,9 +666,8 @@ def _tiled_batched_fwd(x, values, indices, counts, n_in, bn, bm, bo):
 
 
 def _tiled_batched_bwd(n_in, bn, bm, bo, res, dy):
-    from .tile_format import tiled_to_dense
     x, values, indices, counts = res
-    e, o, nb, kb = values.shape
+    nb = values.shape[1]
     w = jax.vmap(lambda v, i, c: tiled_to_dense(
         TiledBalanced(v, i, c, n_in=n_in, bn=bn)))(values, indices, counts)
     dx = jnp.einsum("emo,eon->emn", dy, w,
@@ -685,12 +675,8 @@ def _tiled_batched_bwd(n_in, bn, bm, bo, res, dy):
     dw = jnp.einsum("emo,emn->eon", dy, x,
                     preferred_element_type=jnp.float32)
     dw = jnp.pad(dw, ((0, 0), (0, 0), (0, nb * bn - n_in)))
-    cols = jnp.arange(nb)[None, None, :, None] * bn + indices  # [E,O,NB,KB]
-    gathered = jnp.take_along_axis(
-        dw.reshape(e, o, 1, -1), cols.reshape(e, o, 1, -1),
-        axis=3).reshape(e, o, nb, kb)
-    valid = jnp.arange(kb)[None, None, None, :] < counts[..., None]
-    dvals = jnp.where(valid, gathered, 0.0).astype(values.dtype)
+    dvals = jax.vmap(lambda d, i, c: _slot_grads(d, i, c, bn))(
+        dw, indices, counts).astype(values.dtype)
     return dx, dvals, None, None
 
 
@@ -713,17 +699,15 @@ def _tiled_spmm_batched_q(x, values, indices, counts, scales, n_in, bn, bm,
     if impl == "pallas":
         _fault_trip("pallas", bm=bm, bo=bo, bn=bn, batched=True)
         e, m, _ = x.shape
-        o, nb = indices.shape[1], indices.shape[2]
+        nb, o = indices.shape[1], indices.shape[2]
         mp, op_ = _round_up(m, bm), _round_up(o, bo)
         xp = jnp.pad(x, ((0, 0), (0, mp - m), (0, nb * bn - x.shape[2])))
         vp, ip, sp = values, indices, scales
         if op_ != o:
-            vp = jnp.pad(values, ((0, 0), (0, op_ - o), (0, 0), (0, 0)))
-            ip = jnp.pad(indices, ((0, 0), (0, op_ - o), (0, 0), (0, 0)))
-            sp = jnp.pad(scales, ((0, 0), (0, op_ - o), (0, 0)))
+            vp, ip = _pad_rows(values, op_ - o), _pad_rows(indices, op_ - o)
+            sp = _pad_rows(scales, op_ - o, row_axis=-1)
         y = tiled_balanced_spmm_batched_pallas(
-            xp, vp, ip, bn=bn, bm=bm, bo=bo, scales=sp, quant=quant,
-            interpret=_INTERPRET)
+            xp, vp, ip, bn=bn, bm=bm, bo=bo, scales=sp, quant=quant)
         return y[:, :m, :o].astype(x.dtype)
     _fault_trip("xla_gather" if impl == "xla_gather" else "xla",
                 batched=True)
@@ -782,7 +766,7 @@ def tiled_spmm_batched(x: Array, tb: TiledBalanced, *,
     matmul in ONE kernel dispatch.
 
     ``x``: [G, ..., N]; ``tb`` leaves carry a matching leading group axis
-    (values [G, O, NB, KB]).  This is the MoE expert path: G is the expert
+    (values [G, NB, O, KB]).  This is the MoE expert path: G is the expert
     axis of a plan's per-expert encodings (shared BlockChoice, so one set
     of static bm/bo/KB covers the whole grid).  The expert axis is a Pallas
     grid dimension — the previous per-expert `lax.scan` paid E sequential
@@ -794,12 +778,12 @@ def tiled_spmm_batched(x: Array, tb: TiledBalanced, *,
     """
     lead = x.shape[1:-1]
     e = x.shape[0]
-    o = tb.indices.shape[1]
+    o = tb.indices.shape[2]
     x3 = x.reshape(e, -1, x.shape[-1])
     n_eff = tb.n_in
     if tb.perm is not None:
         perm = tb.perm
-        npack = tb.indices.shape[2] * tb.bn
+        npack = tb.indices.shape[1] * tb.bn
         x3 = jnp.pad(x3, ((0, 0), (0, 0), (0, npack - x3.shape[2])))
         if perm.ndim > 1:
             # lead-broadcast leaf: one (identical) perm row per expert
@@ -910,8 +894,7 @@ def bitmap_spmm(x: Array, bitmap: Array, packed: Array, offsets: Array, *,
     bmp = jnp.pad(bitmap, ((0, op_ - o), (0, 0)))
     pak = jnp.pad(packed, ((0, op_ - o), (0, 0)))
     off = jnp.pad(offsets, ((0, op_ - o), (0, 0)))
-    y = bitmap_spmm_pallas(xp, bmp, pak, off, bm=bm, bo=bo, bn=bn,
-                           interpret=_INTERPRET)
+    y = bitmap_spmm_pallas(xp, bmp, pak, off, bm=bm, bo=bo, bn=bn)
     return y[:m, :o].astype(x.dtype).reshape(*lead, o)
 
 

@@ -81,14 +81,13 @@ def sparse_conv2d(x: Array, values: Array, indices: Array, n_in: int, *,
     hp, wp = xp.shape[1], xp.shape[2]
     ho = (hp - hk) // stride + 1
     wo = (wp - wk) // stride + 1
-    co = values.shape[0]
 
     rows_per_chunk = max(1, chunk_elems // max(b * wo * feat, 1))
     if rows_per_chunk >= ho:
         patches = im2col(xp, hk, wk, stride=stride, padding="VALID")
         y = matmul_fn(patches.reshape(b * ho * wo, feat), values, indices,
                       n_in=n_in)
-        return y.reshape(b, ho, wo, co)
+        return y.reshape(b, ho, wo, -1)
 
     outs = []
     for r0 in range(0, ho, rows_per_chunk):
@@ -98,5 +97,5 @@ def sparse_conv2d(x: Array, values: Array, indices: Array, n_in: int, *,
         patches = im2col(slab, hk, wk, stride=stride, padding="VALID")
         y = matmul_fn(patches.reshape(b * (r1 - r0) * wo, feat), values,
                       indices, n_in=n_in)
-        outs.append(y.reshape(b, r1 - r0, wo, co))
+        outs.append(y.reshape(b, r1 - r0, wo, -1))
     return jnp.concatenate(outs, axis=1)

@@ -9,17 +9,22 @@ The tile-local format re-partitions each row's nonzeros by ``bn``-wide
 column blocks of the input dimension, exactly the blocks a grid-``(M, O,
 N/bn)`` kernel walks:
 
-* ``values[O, NB, KB]``  — nonzero values, zero-padded per block
-* ``indices[O, NB, KB]`` — *block-local* column indices in ``[0, bn)``
-* ``counts[O, NB]``      — true nonzeros per (row, block)
+* ``values[NB, O, KB]``  — nonzero values, zero-padded per block
+* ``indices[NB, O, KB]`` — *block-local* column indices in ``[0, bn)``
+* ``counts[NB, O]``      — true nonzeros per (block, row)
 
 ``KB`` is the per-block capacity (max count, rounded up for sublane
-alignment).  This is where the model/hardware co-design pays off twice:
+alignment).  The block axis leads so that one grid step's weight block
+``[bo, KB]`` is a contiguous tile whose last two dims are (rows, slots) —
+the layout Mosaic can DMA; a ``[O, NB, KB]`` order would need a
+``(bo, 1, KB)`` block, which the TPU compiler refuses.
+
+This is where the model/hardware co-design pays off twice:
 Sense's balanced pruning keeps per-row totals equal (K identical), and for
 magnitude pruning of i.i.d. weights the split across ``NB`` blocks is
 hypergeometric, so per-block counts concentrate near ``K/NB`` — ``KB`` sits
 close to the mean and the zero padding stays small (`block_imbalance`
-measures the slack).  The kernel scatter-decodes one ``[bo, bn]`` dense tile
+measures the slack).  The kernel decodes one ``[bo, bn]`` dense tile
 per grid step and feeds the MXU a rank-2 ``[bm, bn] x [bn, bo]`` product;
 padded entries carry value 0 and index 0, so the decode needs no count
 masking at runtime (``counts`` is for diagnostics and storage accounting).
@@ -65,9 +70,9 @@ def _round_up(x: int, m: int) -> int:
 @dataclasses.dataclass
 class TiledBalanced:
     """Block-partitioned balanced-sparse matrix (see module docstring)."""
-    values: Array    # [O, NB, KB]
-    indices: Array   # [O, NB, KB] int32, block-local in [0, bn)
-    counts: Array    # [O, NB] int32, true NZE per block
+    values: Array    # [NB, O, KB]
+    indices: Array   # [NB, O, KB] int32, block-local in [0, bn)
+    counts: Array    # [NB, O] int32, true NZE per block
     n_in: int        # dense input dimension (NB * bn >= n_in)
     bn: int          # column-block width
     # Optional column-combining permutation (see module docstring):
@@ -76,29 +81,31 @@ class TiledBalanced:
     # [L, E, NB*bn]) so per-layer pytree slicing stays shape-consistent.
     perm: Array | None = None
     # Block quantization (QUANT_MODES): when quant != "none", ``values``
-    # holds the narrow encoding (int8 [O, NB, KB]; int4 packed uint8
-    # [O, NB, KB/2], two nibbles per byte) and ``scales`` the per-block f32
+    # holds the narrow encoding (int8 [NB, O, KB]; int4 packed uint8
+    # [NB, O, KB/2], two nibbles per byte) and ``scales`` the per-block f32
     # absmax/qmax factors, shaped like ``counts``.  ``indices`` always keeps
-    # the *logical* [O, NB, KB] shape, so geometry reads from it below.
+    # the *logical* [NB, O, KB] shape, so geometry reads from it below.
+    # Stacked plans prepend lead axes ([L, ...], [L, E, ...]) to every leaf.
     scales: Array | None = None
     quant: str = "none"
 
     @property
     def n_out(self) -> int:
-        return self.indices.shape[0]
+        return self.indices.shape[-2]
 
     @property
     def nb(self) -> int:
-        return self.indices.shape[1]
+        return self.indices.shape[-3]
 
     @property
     def kb(self) -> int:
-        return self.indices.shape[2]
+        return self.indices.shape[-1]
 
     @property
     def k(self) -> int:
         """Total nonzeros per row (the flat format's K)."""
-        return int(np.asarray(jnp.sum(self.counts[0])))
+        return int(np.asarray(jnp.sum(self.counts[..., 0].reshape(
+            -1, self.nb)[0])))
 
     def to_dense(self) -> Array:
         return tiled_to_dense(self)
@@ -123,16 +130,18 @@ jax.tree_util.register_pytree_node(
     TiledBalanced, TiledBalanced.tree_flatten, TiledBalanced.tree_unflatten)
 
 
+def _block_counts(idx: np.ndarray, nb: int, bn: int) -> np.ndarray:
+    """Per-(row, block) entry counts ``[O, NB]`` of a flat host index array."""
+    o = idx.shape[0]
+    flat = (np.arange(o, dtype=np.int64)[:, None] * nb + idx // bn).ravel()
+    return np.bincount(flat, minlength=o * nb).reshape(o, nb)
+
+
 def max_block_count(indices, n_in: int, bn: int) -> int:
     """Concrete KB for a flat index array: max per-(row, block) entry count,
     rounded up to a sublane multiple.  Host-side (requires concrete data)."""
-    idx = np.asarray(indices)
-    o, k = idx.shape
-    nb = -(-n_in // bn)
-    blk = idx // bn
-    counts = np.zeros((o, nb), np.int64)
-    np.add.at(counts, (np.arange(o)[:, None], blk), 1)
-    return max(_KB_ROUND, _round_up(int(counts.max()), _KB_ROUND))
+    counts = _block_counts(np.asarray(indices), -(-n_in // bn), bn)
+    return max(_KB_ROUND, _round_up(int(counts.max(initial=0)), _KB_ROUND))
 
 
 def pack_columns(pattern, bn: int) -> np.ndarray:
@@ -157,17 +166,41 @@ def pack_columns(pattern, bn: int) -> np.ndarray:
     if nb <= 1:
         return np.arange(npad, dtype=np.int32)
     order = np.argsort(-mask.sum(axis=0), kind="stable")
-    block_rows = np.zeros((nb, o), np.int64)   # per-block per-row NZE so far
+    # Adding column c to block b raises the block's max row count by one
+    # exactly when c hits a row already at that max, so the greedy only
+    # needs each block's max (``level``) and the set of rows at it
+    # (``at_max``), kept as row bitsets: one AND per block per column
+    # instead of a pass over every (block, row) count.
+    cols_t = np.ascontiguousarray(mask.T)                   # [n, o]
+
+    def bits(rows_bool):                    # [..., o] bool -> [..., w] u64
+        b8 = np.packbits(rows_bool, axis=-1)
+        pad = -b8.shape[-1] % 8
+        if pad:
+            b8 = np.concatenate(
+                [b8, np.zeros((*b8.shape[:-1], pad), np.uint8)], axis=-1)
+        return b8.view(np.uint64)
+
+    col_bits = bits(cols_t)                                 # [n, w]
+    block_rows = np.zeros((nb, o), np.int32)   # per-block per-row NZE so far
+    level = np.zeros(nb, np.int64)             # per-block max row count
+    at_max = np.repeat(bits(np.ones((1, o), bool)), nb, axis=0)
     fill = np.zeros(nb, np.int64)              # slots used per block
+    full = np.iinfo(np.int64).max
     slots: list[list[int]] = [[] for _ in range(nb)]
     for c in order:
-        col = mask[:, c]
-        newmax = (block_rows + col[None, :]).max(axis=1) if o else fill * 0
-        newmax = np.where(fill < bn, newmax, np.iinfo(np.int64).max)
+        hits = (at_max & col_bits[c]).any(axis=1)
+        newmax = np.where(fill < bn, level + hits, full)
         b = int(np.lexsort((fill, newmax))[0])
         slots[b].append(int(c))
+        col = cols_t[c]
         block_rows[b] += col
         fill[b] += 1
+        if hits[b]:
+            level[b] += 1
+            at_max[b] = bits(block_rows[b] == level[b])
+        else:
+            at_max[b] |= bits(col & (block_rows[b] == level[b]))
     pad_pool = iter(range(n, npad))
     perm = np.empty(npad, np.int32)
     for b, s in enumerate(slots):
@@ -211,32 +244,40 @@ def encode_tiled(values, indices, n_in: int, *, bn: int,
         else:
             kb = max(_KB_ROUND, _round_up(min(k, bn), _KB_ROUND))
 
-    rows = np.arange(o)[:, None]
     if idx_concrete:
         idx = np.asarray(indices)
-        # stable sort by block id (indices from to_balanced_sparse are
-        # already ascending; this only defends against unsorted callers).
-        order = np.argsort(idx // bn, axis=1, kind="stable")
-        idx_s = np.take_along_axis(idx, order, axis=1)
-        blk = idx_s // bn
-        counts = np.zeros((o, nb), np.int32)
-        np.add.at(counts, (rows, blk), 1)
-        if int(counts.max()) > kb:
+        # ascending column order (indices from to_balanced_sparse already
+        # are; this defends against unsorted callers): it groups each row
+        # by block and keeps every block's live indices sorted.
+        order = None
+        if k > 1 and not (idx[:, 1:] > idx[:, :-1]).all():
+            order = np.argsort(idx, axis=1, kind="stable")
+            idx = np.take_along_axis(idx, order, axis=1)
+        counts = _block_counts(idx, nb, bn).astype(np.int32)   # [O, NB]
+        if int(counts.max(initial=0)) > kb:
             raise ValueError(f"kb={kb} < max per-block count {counts.max()}")
-        off = np.cumsum(counts, axis=1) - counts          # exclusive
-        slot = np.arange(k)[None, :] - np.take_along_axis(off, blk, axis=1)
-        ti = np.zeros((o, nb, kb), np.int32)
-        ti[rows, blk, slot] = idx_s % bn
-        val_s = jnp.take_along_axis(jnp.asarray(values), jnp.asarray(order),
-                                    axis=1)
-        tv = jnp.zeros((o, nb, kb), values.dtype).at[
-            jnp.asarray(rows), jnp.asarray(blk), jnp.asarray(slot)].set(val_s)
-        return TiledBalanced(tv, jnp.asarray(ti), jnp.asarray(counts),
-                             n_in=n_in, bn=bn)
+        # every tile slot gathers its entry: slot s of block b holds the
+        # row's (off[b] + s)-th sorted entry, or position k (a zero pad)
+        off = (np.cumsum(counts, axis=1) - counts).astype(np.int32)
+        slots = np.arange(kb, dtype=np.int32)
+        src = np.where(slots < counts[:, :, None], off[:, :, None] + slots,
+                       np.int32(k)).reshape(o, nb * kb)      # [O, NB*KB]
+        local = np.pad(idx % bn, ((0, 0), (0, 1))).astype(np.int32)
+        ti = np.ascontiguousarray(np.take_along_axis(local, src, axis=1)
+                                  .reshape(o, nb, kb).transpose(1, 0, 2))
+        if order is not None:           # sorted position -> input position
+            src = np.take_along_axis(np.pad(order, ((0, 0), (0, 1)),
+                                            constant_values=k), src, axis=1)
+        src = src.reshape(o, nb, kb)
+        vpad = jnp.pad(jnp.asarray(values), ((0, 0), (0, 1)))
+        tv = jnp.take_along_axis(vpad, jnp.asarray(src.reshape(o, -1)),
+                                 axis=1).reshape(o, nb, kb)
+        return TiledBalanced(jnp.transpose(tv, (1, 0, 2)), jnp.asarray(ti),
+                             jnp.asarray(counts.T), n_in=n_in, bn=bn)
 
     # Fully traced path (indices themselves are being transformed).
     jrows = jnp.arange(o)[:, None]
-    order = jnp.argsort(indices // bn, axis=1, stable=True)
+    order = jnp.argsort(indices, axis=1, stable=True)
     idx_s = jnp.take_along_axis(indices, order, axis=1)
     val_s = jnp.take_along_axis(values, order, axis=1)
     blk = idx_s // bn
@@ -244,11 +285,11 @@ def encode_tiled(values, indices, n_in: int, *, bn: int,
                      axis=1).astype(jnp.int32)
     off = jnp.cumsum(counts, axis=1) - counts
     slot = jnp.arange(k)[None, :] - jnp.take_along_axis(off, blk, axis=1)
-    tv = jnp.zeros((o, nb, kb), values.dtype).at[jrows, blk, slot].set(
+    tv = jnp.zeros((nb, o, kb), values.dtype).at[blk, jrows, slot].set(
         val_s, mode="drop")
-    ti = jnp.zeros((o, nb, kb), jnp.int32).at[jrows, blk, slot].set(
+    ti = jnp.zeros((nb, o, kb), jnp.int32).at[blk, jrows, slot].set(
         (idx_s % bn).astype(jnp.int32), mode="drop")
-    return TiledBalanced(tv, ti, counts, n_in=n_in, bn=bn)
+    return TiledBalanced(tv, ti, counts.T, n_in=n_in, bn=bn)
 
 
 def pack_int4(q: Array) -> Array:
@@ -329,26 +370,38 @@ def dequantize_tiled(tb: TiledBalanced) -> TiledBalanced:
                          bn=tb.bn, perm=tb.perm)
 
 
-def tiled_to_dense(tb: TiledBalanced) -> Array:
-    """Densify to ``[O, n_in]`` (reference/inverse of `encode_tiled`).
+def _leaf_perm_jnp(perm) -> Array:
+    """`_leaf_perm` for traced/device perms (collapse lead-broadcast rows)."""
+    return perm.reshape(-1, perm.shape[-1])[0] if perm.ndim > 1 else perm
 
-    Packed encodings are unpermuted back to original column order; padded
-    slots map to padding columns >= n_in under ``perm`` by construction,
-    but padded *tile* slots (value 0, local index 0) may scatter a zero
-    onto a real column — harmless for ``.add``.  Quantized encodings are
-    dequantized first (the format's f32 reconstruction is the reference).
+
+def tiled_to_dense(tb: TiledBalanced) -> Array:
+    """Densify one (unstacked) encoding to ``[O, n_in]`` — the reference
+    and inverse of `encode_tiled`, dequantized first for quantized tiles.
+
+    The kernel's own decode, in XLA: a loop over the KB slots adds each
+    live slot's value where its block-local index matches the column.  No
+    scatter and no search, so it lowers to plain elementwise passes on CPU
+    and TPU alike.  Packed encodings come back in original column order
+    (``perm`` maps packed position -> column).
     """
-    tb = dequantize_tiled(tb)
-    o, nb, kb = tb.values.shape
-    rows = jnp.arange(o)[:, None, None]
-    cols = jnp.arange(nb)[None, :, None] * tb.bn + tb.indices
+    nb, o, kb = tb.indices.shape
+    bn = tb.bn
+    vals = dequantize_values(tb.values, tb.scales, tb.quant, kb)
+    live = jnp.arange(kb, dtype=tb.counts.dtype) < tb.counts[..., None]
+    vals = jnp.where(live, vals, jnp.zeros((), vals.dtype))
+    cols = jnp.arange(bn, dtype=tb.indices.dtype)
+
+    def slot(k, acc):
+        idx = jax.lax.dynamic_index_in_dim(tb.indices, k, axis=2)
+        val = jax.lax.dynamic_index_in_dim(vals, k, axis=2)
+        return acc + jnp.where(idx == cols, val, jnp.zeros((), vals.dtype))
+    dense = jax.lax.fori_loop(0, kb, slot,
+                              jnp.zeros((nb, o, bn), vals.dtype))
+    dense = jnp.transpose(dense, (1, 0, 2)).reshape(o, nb * bn)
     if tb.perm is not None:
-        perm = tb.perm
-        if perm.ndim > 1:                      # lead-broadcast stacked leaf
-            perm = perm.reshape(-1, perm.shape[-1])[0]
-        cols = jnp.take(perm.astype(jnp.int32), cols)
-    dense = jnp.zeros((o, nb * tb.bn), tb.values.dtype)
-    dense = dense.at[rows, cols].add(tb.values)
+        inv = jnp.argsort(_leaf_perm_jnp(tb.perm).astype(jnp.int32))
+        dense = jnp.take(dense, inv, axis=1)
     return dense[:, :tb.n_in]
 
 
@@ -365,8 +418,8 @@ def tiled_to_flat(tb: TiledBalanced):
     (the flat consumers carry no scales).
     """
     tb = dequantize_tiled(tb)
-    idx = np.asarray(tb.indices)
-    cnt = np.asarray(tb.counts)
+    idx = np.asarray(tb.indices).transpose(1, 0, 2)            # [O, NB, KB]
+    cnt = np.asarray(tb.counts).T
     o, nb, kb = idx.shape
     totals = cnt.sum(axis=1)
     if o and not (totals == totals[0]).all():
@@ -384,8 +437,9 @@ def tiled_to_flat(tb: TiledBalanced):
     order = np.argsort(~valid.reshape(o, -1), axis=1, kind="stable")[:, :k]
     flat_idx = np.take_along_axis(gcols.reshape(o, -1), order,
                                   axis=1).astype(np.int32)
-    flat_vals = jnp.take_along_axis(tb.values.reshape(o, -1),
-                                    jnp.asarray(order), axis=1)
+    flat_vals = jnp.take_along_axis(
+        jnp.transpose(tb.values, (1, 0, 2)).reshape(o, -1),
+        jnp.asarray(order), axis=1)
     if tb.perm is not None:
         # packed block order is not ascending in original columns; the flat
         # consumers (searchsorted densify, gather paths) require ascending

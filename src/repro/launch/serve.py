@@ -21,7 +21,12 @@ measured autotuner (`kernels/autotune.py`): warm cache entries win, cold
 keys fall back to the static VMEM model ("cached") or are swept and
 persisted ("sweep"); the report lists tuned-vs-static choice deltas and
 the per-source mix.  Only the Pallas impl consumes block sizes, so tuning
-bites with ``--impl pallas`` (or auto on real TPU).
+bites with ``--impl pallas`` (or auto on a TPU backend).
+
+The "dense" throughput baseline runs the masked-dense reference weights
+(the plan's pruned weights densified — same shapes and FLOPs as the
+unpruned model); the unpruned projections are released once the plan and
+the reference exist.
 """
 from __future__ import annotations
 
@@ -176,12 +181,12 @@ def traffic_mode(bundle, serve_params, cfg, args) -> dict:
     """
     from ..serving import ServingEngine, contiguous_engine
     from ..serving import traffic as tr
-    from .mesh import make_host_mesh, make_production_mesh
-    # shard the pool planes + page-table lookups when devices exist; the
-    # degenerate 1-device mesh keeps the NamedSharding path exercised on
-    # the CPU container (values are identical either way — parity holds)
-    mesh = (make_production_mesh() if jax.device_count() > 1
-            else make_host_mesh())
+    from .mesh import make_host_mesh
+    # shard the pool planes + page-table lookups over this process's
+    # devices; on one device the degenerate (1, 1) mesh keeps the
+    # NamedSharding path exercised (values are identical either way —
+    # parity holds)
+    mesh = make_host_mesh()
     rng = np.random.default_rng(args.seed)
     prompt_lens = (args.prompt_len // 2, args.prompt_len)
     gen_steps = (max(args.gen_steps // 4, 2), args.gen_steps)
@@ -355,7 +360,7 @@ def main(argv=None):
     cfg = dataclasses.replace(cfg, sparse_serving=True)
     from ..models import build_model
     bundle = build_model(cfg)
-    params = bundle.init(jax.random.key(0))
+    params = jax.jit(bundle.init)(jax.random.key(0))
     prompt = jax.random.randint(jax.random.key(1),
                                 (args.batch, args.prompt_len), 0,
                                 cfg.vocab_size)
@@ -375,9 +380,13 @@ def main(argv=None):
         print(f"[serve] --attn-only is inapplicable to family {cfg.family} "
               "(no separate attention projections are planned); planning "
               "the full projection family")
+    t_plan = time.monotonic()
     plan = engine_plan.plan_model(cfg, params, **plan_kwargs)
+    jax.block_until_ready(plan)
+    plan_build_s = time.monotonic() - t_plan
     print(f"[serve] family={cfg.family} layer plan ({len(plan.layers)} "
-          f"projection groups x {cfg.n_layers} layers):")
+          f"projection groups x {cfg.n_layers} layers, built in "
+          f"{plan_build_s:.1f}s):")
     print(plan.summary())
     if args.tune != "off":
         deltas = plan.tune_deltas()
@@ -404,8 +413,17 @@ def main(argv=None):
             print(f"[serve/guard] ladder: {d.layer} {d.from_impl} -> "
                   f"{d.to_impl} ({d.action}: {d.reason})")
 
-    sparse_params = {**params, "sparse_plan": plan}
+    # The unpruned projection weights are dead once the plan exists: sparse
+    # serving reads the plan and the dense baseline reads the masked-dense
+    # reference (same shapes, same FLOPs).  Dropping them first frees a
+    # whole f32 copy of the projections (4.3 GB at olmo-1b), and the
+    # reference then keeps the plan's value dtype — exactly what the
+    # model's matmuls cast every weight to — so three weight copies never
+    # coexist on a 16 GiB chip.
+    params = {**params, "blocks": {nm: w for nm, w in params["blocks"].items()
+                                   if nm not in plan.layers}}
     ref_params = engine_plan.masked_dense_params(params, plan)
+    sparse_params = {**params, "sparse_plan": plan}
 
     # one jitted pair shared by the parity check and both throughput modes:
     # jax.jit caches per argument pytree structure, so dense, masked-dense
@@ -435,18 +453,30 @@ def main(argv=None):
 
     # ---- correctness: sparse plan == masked dense, and the balanced
     # kernels are actually on the traced token path ------------------------
-    # quantized plans compare against the *dequantized* masked-dense
+    # The gate runs an f32-compute twin of the model on the same params and
+    # plan, every matmul at HIGHEST precision.  In bf16 the rounding of
+    # the hidden state puts a noise floor under the logits (about sqrt(d)
+    # bf16 ulps: 0.02-0.07 at d_model 2048) that any change of summation
+    # order crosses, so a bf16 end-to-end gate cannot tell a wrong kernel
+    # from a reordered sum; the bf16 gap is reported, not gated.
+    # Quantized plans compare against the *dequantized* masked-dense
     # reference (`masked_dense_params` densifies through the scales), so
     # the parity diff measures kernel-vs-reference round-off, not the
     # quantization error itself; the wider tol covers accumulation-order
     # spread of the in-kernel dequant across layers
-    tol = 1e-4 if jnp.dtype(cfg.compute_dtype) == jnp.float32 else 2e-2
+    pcfg = dataclasses.replace(cfg, compute_dtype="float32")
+    tol = 1e-4
     if args.quant != "none":
         tol = max(tol, 5e-2)
     engine_execute.reset_stats()
-    diff = _parity_check(prefill_fn, sparse_params, ref_params, prompt,
-                         tol=tol)
+    with jax.default_matmul_precision("highest"):
+        diff = _parity_check(jax.jit(build_model(pcfg).prefill),
+                             sparse_params, ref_params, prompt, tol=tol)
     stats = engine_execute.stats()
+    bf16_diff = float(jnp.max(jnp.abs(
+        prefill_fn(sparse_params, {"tokens": prompt})[0].astype(jnp.float32)
+        - prefill_fn(ref_params, {"tokens": prompt})[0].astype(
+            jnp.float32))))
     if args.guard and not stats.get("balanced_spmm"):
         # the guarded pass already compiled this params structure, so the
         # jitted parity calls hit the executable cache without re-tracing
@@ -469,8 +499,10 @@ def main(argv=None):
         # legitimately dense)
         assert stats.get("expert_balanced_spmm", 0) > 0, \
             f"MoE expert layers never hit the per-expert path ({stats})"
-    print(f"[serve] parity sparse vs masked-dense: max |dlogit| = {diff:.2e}"
-          f" (tol {tol:g});  engine dispatches: {stats}")
+    print(f"[serve] parity sparse vs masked-dense (f32 compute): max "
+          f"|dlogit| = {diff:.2e} (tol {tol:g}); served {cfg.compute_dtype} "
+          f"logits differ by {bf16_diff:.2e} (not gated);  engine "
+          f"dispatches: {stats}")
 
     # ---- throughput ------------------------------------------------------
     results = {}
@@ -483,7 +515,7 @@ def main(argv=None):
                      f"{TRANSFORMER_FAMILIES}; {cfg.family} has O(1) "
                      "recurrent state (nothing to page)")
         results["traffic"] = traffic_mode(bundle, sparse_params, cfg, args)
-    for mode, p in () if args.traffic else (("dense", params),
+    for mode, p in () if args.traffic else (("dense", ref_params),
                                             ("sparse", sparse_params)):
         # warm up (compile) outside the timed region
         greedy_generate(bundle, p, prompt, 1, max_len,
@@ -514,7 +546,11 @@ def main(argv=None):
         "family": cfg.family, "quant": args.quant,
         "mode_mix": plan.mode_mix(), "impl_mix": plan.impl_mix(),
         "sparse_layers": plan.sparse_layer_count,
-        "parity_max_abs_diff": diff, "engine_stats": stats,
+        "degraded_mix": plan.degraded_mix(),
+        "quarantined": list(plan.quarantined()),
+        "build_s": plan_build_s,
+        "parity_max_abs_diff": diff, "parity_tol": tol,
+        "served_logits_max_abs_diff": bf16_diff, "engine_stats": stats,
         "tune": {"mode": args.tune, "sources": plan.tuned_mix(),
                  "deltas": [[nm, list(t), list(s)]
                             for nm, t, s in plan.tune_deltas()]},
@@ -545,4 +581,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

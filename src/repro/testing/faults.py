@@ -88,11 +88,11 @@ def corrupt_tile_encoding(plan: ModelPlan, layer: str | None = None,
         elif kind == "nan":
             vals.reshape(-1)[0] = np.nan
         else:  # imbalance: give row 0 one fewer NZE than the rest
-            flat = cnt.reshape(-1, cnt.shape[-1])
-            nz = np.nonzero(flat[0])[0]
+            row0 = cnt.reshape(-1, *cnt.shape[-2:])[0, :, 0]   # [NB] view
+            nz = np.nonzero(row0)[0]
             if not nz.size:
                 raise ValueError(f"{name}: row 0 has no NZE to drop")
-            flat[0, nz[0]] -= 1
+            row0[nz[0]] -= 1
         new = dataclasses.replace(
             w, values=jnp.asarray(vals).astype(w.values.dtype),
             indices=jnp.asarray(idx), counts=jnp.asarray(cnt))

@@ -17,10 +17,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import (dim_spec, dp_axes, logical_spec,
                                         shard_batch)
+from repro.launch.mesh import make_mesh
 
 
 def mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_dim_spec_divisibility_guard():
@@ -53,8 +54,9 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.distributed.sharding import tree_shardings
     from repro.configs.base import ShapeSpec
     from repro.launch import hlo_cost
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     failures = []
     for arch in ["olmo-1b", "rwkv6-3b", "zamba2-1.2b", "deepseek-moe-16b"]:
         cfg = get_smoke(arch)
@@ -110,3 +112,40 @@ def test_dryrun_results_complete():
         assert r["flops_per_device"] > 0
         assert r["roofline"]["dominant"] in ("compute", "memory",
                                              "collective")
+
+
+def test_host_mesh_is_auto_over_local_devices():
+    """The serving mesh covers this process's devices as (data, model) with
+    ``Auto`` axes — the axis type `with_sharding_constraint` needs."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.devices.size == len(jax.devices())
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """Entry points keep the persistent compile cache in
+    $JAX_COMPILATION_CACHE_DIR when set (JAX reads it; nothing is set in
+    code), else in the one fixed in-checkout directory."""
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            assert got == str(compile_cache.DEFAULT_DIR)
+            assert jax.config.jax_compilation_cache_dir == got
+            assert compile_cache.DEFAULT_DIR.parent == \
+                compile_cache.REPO_ROOT
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == was
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -19,6 +19,7 @@ from repro.core.pruning import (balanced_prune_conv, balanced_prune_rows,
                                 random_prune)
 from repro.engine import execute as engine_execute
 from repro.engine import plan as engine_plan
+from repro.launch.mesh import make_mesh
 
 
 def _fc_plan(key=0, o=48, n=96, sparsity=0.6, **kw):
@@ -335,7 +336,7 @@ def test_plan_specs_encoded_values_not_replicated():
     m = build_model(cfg)
     params = m.init(jax.random.key(0))
     plan = engine_plan.plan_model(cfg, params, sparsity=0.5)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     specs = engine_plan.plan_specs(plan, mesh)
     assert jax.tree_util.tree_structure(
         specs, is_leaf=lambda x: isinstance(x, P)).num_leaves == \
@@ -345,11 +346,16 @@ def test_plan_specs_encoded_values_not_replicated():
             else lp.weights
         assert any(d is not None for d in vspec), (nm, vspec)
         assert vspec[0] is None, "stacked L axis must stay replicated"
+        # tiled leaves are [L, (E,) NB, O, KB]; dense ones [L, (E,) O, N]
+        tiled = plan.layers[nm].weights.__class__.__name__ == "TiledBalanced"
         if plan.layers[nm].spec.experts:
             assert vspec[1] == "model", "expert axis is expert-parallel"
-            assert vspec[2] == "data", "O axis is FSDP-sharded"
+            o_axis = 3 if tiled else 2
         else:
-            assert vspec[1] == "data", "O axis is FSDP-sharded"
+            o_axis = 2 if tiled else 1
+        assert vspec[o_axis] == "data", "O axis is FSDP-sharded"
+        if tiled:
+            assert vspec[o_axis - 1] is None, "NB axis stays whole"
 
 
 SHARDED_PLAN_SCRIPT = textwrap.dedent("""
@@ -359,7 +365,8 @@ SHARDED_PLAN_SCRIPT = textwrap.dedent("""
     from repro.configs import get_smoke
     from repro.models import build_model
     from repro.engine import plan as engine_plan
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_smoke("deepseek-moe-16b")
     m = build_model(cfg)
     params = m.init(jax.random.key(0))

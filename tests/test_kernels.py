@@ -104,8 +104,8 @@ def test_tile_format_roundtrip_and_balance():
     assert tb.nb == -(-n // bn) and tb.bn == bn
     # block-local indices stay inside their block
     assert int(jnp.max(tb.indices)) < bn
-    # counts preserve the per-row total K (the balance invariant)
-    np.testing.assert_array_equal(np.asarray(jnp.sum(tb.counts, axis=1)),
+    # counts [NB, O] preserve the per-row total K (the balance invariant)
+    np.testing.assert_array_equal(np.asarray(jnp.sum(tb.counts, axis=0)),
                                   np.full(o, k))
     np.testing.assert_allclose(np.asarray(tiled_to_dense(tb)),
                                np.asarray(sp.to_dense()), atol=0)

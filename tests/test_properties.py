@@ -94,14 +94,14 @@ def test_tiled_encode_decode_roundtrip(o, n, k, bn, seed, dtype):
     # geometry: covers the non-divisible tail block
     assert tb.nb == -(-n // bn)
     assert int(jnp.max(tb.indices)) < bn
-    # per-row totals preserve the balance invariant K
-    np.testing.assert_array_equal(np.asarray(jnp.sum(tb.counts, axis=1)),
+    # per-row totals (counts are [NB, O]) preserve the balance invariant K
+    np.testing.assert_array_equal(np.asarray(jnp.sum(tb.counts, axis=0)),
                                   np.full(o, k))
     # exact round-trip (scatter/gather moves bits, never arithmetic)
     np.testing.assert_array_equal(np.asarray(tiled_to_dense(tb)),
                                   np.asarray(sp.to_dense()))
     # zero-count blocks decode to all-zero columns
-    counts = np.asarray(tb.counts)
+    counts = np.asarray(tb.counts).T                    # [O, NB]
     dense = np.asarray(tiled_to_dense(tb))
     for r, b in zip(*np.nonzero(counts == 0)):
         lo, hi = b * bn, min((b + 1) * bn, n)
@@ -325,17 +325,18 @@ def test_quantize_all_zero_blocks_encode_scale_zero(o, nblocks, quant, seed):
                     jnp.float32)
     sp = to_balanced_sparse(w, k=4)
     tb = encode_tiled(sp.values, sp.indices, n, bn=bn)
-    # zero out every block past the first: kept slots with value 0.0
+    # zero out every block past the first ([NB, O, KB]): kept slots with
+    # value 0.0
     vals = np.asarray(tb.values).copy()
-    vals[:, 1:, :] = 0.0
+    vals[1:] = 0.0
     tb = TiledBalanced(jnp.asarray(vals), tb.indices, tb.counts,
                        n_in=tb.n_in, bn=tb.bn)
     qt = quantize_tiled(tb, quant)
     scales = np.asarray(qt.scales)
-    assert not scales[:, 1:].any()
-    assert not np.asarray(qt.values)[:, 1:].any()
+    assert not scales[1:].any()
+    assert not np.asarray(qt.values)[1:].any()
     deq = np.asarray(dequantize_tiled(qt).values)
-    assert np.isfinite(deq).all() and not deq[:, 1:].any()
+    assert np.isfinite(deq).all() and not deq[1:].any()
 
 
 @given(st.integers(2, 10), st.integers(9, 40), st.integers(1, 6),

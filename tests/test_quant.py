@@ -161,7 +161,7 @@ def test_quant_plan_parity_within_block_bound(quant):
     xpad = np.zeros((x.shape[0], nb * bn), np.float32)
     xpad[:, :x.shape[1]] = xa
     xb = xpad.reshape(x.shape[0], nb, bn).sum(-1)          # [M, NB]
-    bound = xb @ (scales.T / 2) + 1e-5                     # [M, O]
+    bound = xb @ (scales / 2) + 1e-5                       # [M, O]
     err = np.abs(got - np.asarray(x @ wm.T))
     assert (err <= bound * (1 + 1e-5)).all()
 
