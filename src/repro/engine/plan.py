@@ -55,6 +55,7 @@ from ..kernels import ops as kernel_ops
 from ..kernels.tile_format import (_KB_ROUND, _round_up, QUANT_MODES,
                                    TiledBalanced, encode_tiled,
                                    quantize_tiled, tiled_to_dense)
+from .. import obs
 from ..launch import cost_model as _cost
 
 Array = jax.Array
@@ -499,40 +500,41 @@ def _tag_for(*, objective: str, dep, ev: Dict[str, Any], mode: str,
 # Single-layer plan construction
 # ---------------------------------------------------------------------------
 
-def _maybe_pack(idx: np.ndarray, vals, pattern2: np.ndarray, n_in: int,
-                bn: int, block_k: int):
-    """Try column-combining packing (`tile_format.pack_columns`) on a flat
-    balanced encoding before tiling.
+def _pack_choice(pattern2: np.ndarray, n_in: int, bn: int, block_k: int):
+    """Column-combining packing (`tile_format.pack_columns`) of the pooled
+    mask ``pattern2`` [rows, n_in] the shared KB is computed over.
 
-    ``idx`` [..., O, K] ascending global indices, ``vals`` the matching
-    value array, ``pattern2`` [rows, n_in] the pooled mask the shared KB is
-    computed over.  Adopted only when the packed per-block capacity
-    strictly shrinks KB (otherwise the permutation costs an input gather
-    for nothing).  Returns ``(idx, vals, block_k, n_enc, perm, pack_kb)``:
-    perm is None when not adopted (and ``n_enc == n_in``); when adopted,
-    indices are remapped into packed column space (re-sorted ascending),
-    ``n_enc`` is the padded packed width NB*bn, and ``pack_kb`` records
-    ``(kb_unpacked, kb_packed)`` for spec provenance.
+    Returns ``(perm, kb_packed)`` when the packed per-block capacity
+    strictly shrinks KB, else None (the permutation would cost an input
+    gather for nothing).  Decided from the mask alone: the packed KB is the
+    mask's block capacity with its padded columns in packed order.
     """
     from ..kernels import tile_format
     nb = -(-n_in // bn)
     if nb <= 1:
-        return idx, vals, block_k, n_in, None, ()
+        return None
     perm = tile_format.pack_columns(pattern2, bn)
-    inv = tile_format.invert_perm(perm)
-    pidx = inv[idx]
+    m = np.pad(np.asarray(pattern2) != 0, ((0, 0), (0, nb * bn - n_in)))
+    counts = np.take(m, perm, axis=1).reshape(len(m), nb, bn).sum(
+        axis=2, dtype=np.uint16)                         # [rows, NB]
+    kb_packed = max(_KB_ROUND, _round_up(int(counts.max(initial=0)),
+                                         _KB_ROUND))
+    if kb_packed >= block_k:
+        return None
+    return perm, kb_packed
+
+
+def _apply_pack(idx: np.ndarray, vals, perm: np.ndarray):
+    """Remap ascending global indices ``idx`` [..., O, K] into packed
+    column space (re-sorted ascending); ``vals`` follow their indices."""
+    from ..kernels import tile_format
+    pidx = tile_format.invert_perm(perm)[idx]
     # packed positions fit 16 bits at every width served here, where the
     # stable argsort is a radix sort (linear, not n log n)
-    key = pidx.astype(np.uint16) if nb * bn <= 1 << 16 else pidx
+    key = pidx.astype(np.uint16) if perm.shape[0] <= 1 << 16 else pidx
     order = np.argsort(key, axis=-1, kind="stable")
     pidx = np.take_along_axis(pidx, order, axis=-1).astype(np.int32)
-    npack = nb * bn
-    kb_packed = tile_format.max_block_count(
-        pidx.reshape(-1, pidx.shape[-1]), npack, bn)
-    if kb_packed >= block_k:
-        return idx, vals, block_k, n_in, None, ()
-    vals = jnp.take_along_axis(vals, jnp.asarray(order), axis=-1)
-    return pidx, vals, kb_packed, npack, perm, (block_k, kb_packed)
+    return pidx, jnp.take_along_axis(vals, jnp.asarray(order), axis=-1)
 
 
 def build_layer_plan(name: str, w: Array, *, mask: Array | None = None,
@@ -704,9 +706,13 @@ def build_layer_plan(name: str, w: Array, *, mask: Array | None = None,
                                 _KB_ROUND))
         if impl == "pallas" or quant != "none":
             n_enc, perm = n, None
-            if impl == "pallas" and pack and kind == "fc":
-                idx, vals, block_k, n_enc, perm, pack_kb = _maybe_pack(
-                    idx, vals, pattern, n, blocks.bn, block_k)
+            pick = _pack_choice(pattern, n, blocks.bn, block_k) \
+                if impl == "pallas" and pack and kind == "fc" else None
+            if pick is not None:
+                perm, kb_packed = pick
+                idx, vals = _apply_pack(idx, vals, perm)
+                pack_kb = (block_k, kb_packed)
+                block_k, n_enc = kb_packed, perm.shape[0]
             # np indices keep encode_tiled on its host (concrete) path
             tb = encode_tiled(vals, idx, n_enc, bn=blocks.bn, kb=block_k)
             weights = TiledBalanced(tb.values, tb.indices, tb.counts,
@@ -885,11 +891,13 @@ def _plan_stacked(nm: str, w: Array, *, sparsity: float, impl: str | None,
     # independent, so [g*O, K] encodes in a single pass — no per-slice
     # device round-trips even at L*E scale): output-major transpose, per-row
     # top-k prune (same stable tie-breaking as balanced_prune_rows), one
-    # host sync for the pattern.
-    wt = jnp.swapaxes(w.reshape(g, n_in, n_out), -1, -2).astype(cd)
-    order = jnp.argsort(-jnp.abs(wt), axis=-1, stable=True)
-    ranks = jnp.argsort(order, axis=-1, stable=True)
-    masks = np.asarray(ranks < k)                         # [g, O, N] bool
+    # host sync for the pattern.  The build's phases are `obs` spans:
+    # plan.prune, plan.blocks, plan.pack, plan.encode.
+    with obs.span("plan.prune", layer=nm):
+        wt = jnp.swapaxes(w.reshape(g, n_in, n_out), -1, -2).astype(cd)
+        order = jnp.argsort(-jnp.abs(wt), axis=-1, stable=True)
+        ranks = jnp.argsort(order, axis=-1, stable=True)
+        masks = np.asarray(ranks < k)                     # [g, O, N] bool
     dep = _cost.get_deployment(deployment)
     if objective not in _cost.OBJECTIVES:
         raise ValueError(f"objective must be one of {_cost.OBJECTIVES}, "
@@ -928,62 +936,72 @@ def _plan_stacked(nm: str, w: Array, *, sparsity: float, impl: str | None,
     packed = False
     pack_kb: Tuple = ()
     if impl_nm == "dense":
-        weights: Any = (wt * masks).reshape(*lead, n_out, n_in)
+        with obs.span("plan.encode", layer=nm):
+            weights: Any = (wt * masks).reshape(*lead, n_out, n_in)
         blk = None
         block_k = 0
         quant = "none"
     else:
         itemsize = cd.itemsize
-        res = autotune.resolve_blocks(m_hint, n_out, n_in, k,
-                                      itemsize=itemsize, impl=impl_nm,
-                                      tune=tune, cache_path=tune_cache,
-                                      dtype=cd, quant=quant)
-        blk, tuned, blk_static = res.blocks, res.source, res.static
-        blk_dec = autotune.resolve_blocks(decode_m, n_out, n_in, k,
+        with obs.span("plan.blocks", layer=nm):
+            res = autotune.resolve_blocks(m_hint, n_out, n_in, k,
                                           itemsize=itemsize, impl=impl_nm,
-                                          tune=tune,
-                                          cache_path=tune_cache,
-                                          dtype=cd, quant=quant).blocks
-        block_k = max(_KB_ROUND, _round_up(
-            mask_block_k(masks.reshape(g * n_out, n_in), bn=blk.bn),
-            _KB_ROUND))
-        # nonzero positions ascending per row (the from_mask layout): the
-        # k largest-magnitude columns, which `order` already lists
-        idx = np.asarray(jnp.sort(order[..., :k], axis=-1)).astype(
-            np.int32)                                     # [g, O, K]
-        vals = jnp.take_along_axis(wt, jnp.asarray(idx), axis=-1)
-        if impl_nm == "pallas" or quant != "none":
-            n_enc, perm = n_in, None
-            if impl_nm == "pallas" and pack:
-                idx, vals, block_k, n_enc, perm, pack_kb = _maybe_pack(
-                    idx, vals, masks.reshape(g * n_out, n_in), n_in,
-                    blk.bn, block_k)
-            tb = encode_tiled(vals.reshape(g * n_out, k),
-                              idx.reshape(g * n_out, k), n_enc,
-                              bn=blk.bn, kb=block_k)
-            nb = tb.nb
-            # encoded as one [NB, g*O, KB] stack; lead axes go in front
-            def restack(a):
-                a = a.reshape(nb, g, n_out, *a.shape[2:])
-                return jnp.moveaxis(a, 1, 0).reshape(*lead, nb, n_out,
-                                                     *a.shape[3:])
-            perm_leaf = None
-            if perm is not None:
-                packed = True
-                # broadcast over lead so per-layer slicing (scan / probes)
-                # keeps a well-formed [.., NB*bn] perm per slice
-                perm_leaf = jnp.asarray(np.ascontiguousarray(
-                    np.broadcast_to(perm, (*lead, perm.shape[0]))) if lead
-                    else perm)
-            weights = TiledBalanced(
-                restack(tb.values), restack(tb.indices), restack(tb.counts),
-                n_in=n_in, bn=blk.bn, perm=perm_leaf)
-            if quant != "none":
-                weights = quantize_tiled(weights, quant)
-        else:
-            weights = BalancedSparse(vals.reshape(*lead, n_out, k),
-                                     jnp.asarray(idx).reshape(
-                                         *lead, n_out, k), n_in)
+                                          tune=tune, cache_path=tune_cache,
+                                          dtype=cd, quant=quant)
+            blk, tuned, blk_static = res.blocks, res.source, res.static
+            blk_dec = autotune.resolve_blocks(decode_m, n_out, n_in, k,
+                                              itemsize=itemsize,
+                                              impl=impl_nm, tune=tune,
+                                              cache_path=tune_cache,
+                                              dtype=cd, quant=quant).blocks
+            block_k = max(_KB_ROUND, _round_up(
+                mask_block_k(masks.reshape(g * n_out, n_in), bn=blk.bn),
+                _KB_ROUND))
+        pick = None
+        if impl_nm == "pallas" and pack:
+            with obs.span("plan.pack", layer=nm):
+                pick = _pack_choice(masks.reshape(g * n_out, n_in), n_in,
+                                    blk.bn, block_k)
+        with obs.span("plan.encode", layer=nm):
+            # nonzero positions ascending per row (the from_mask layout):
+            # the k largest-magnitude columns, which `order` already lists
+            idx = np.asarray(jnp.sort(order[..., :k], axis=-1)).astype(
+                np.int32)                                 # [g, O, K]
+            vals = jnp.take_along_axis(wt, jnp.asarray(idx), axis=-1)
+            if impl_nm == "pallas" or quant != "none":
+                n_enc, perm = n_in, None
+                if pick is not None:
+                    perm, kb_packed = pick
+                    idx, vals = _apply_pack(idx, vals, perm)
+                    pack_kb = (block_k, kb_packed)
+                    block_k, n_enc = kb_packed, perm.shape[0]
+                tb = encode_tiled(vals.reshape(g * n_out, k),
+                                  idx.reshape(g * n_out, k), n_enc,
+                                  bn=blk.bn, kb=block_k)
+                nb = tb.nb
+                # encoded as one [NB, g*O, KB] stack; lead axes go in front
+                def restack(a):
+                    a = a.reshape(nb, g, n_out, *a.shape[2:])
+                    return jnp.moveaxis(a, 1, 0).reshape(*lead, nb, n_out,
+                                                         *a.shape[3:])
+                perm_leaf = None
+                if perm is not None:
+                    packed = True
+                    # broadcast over lead so per-layer slicing (scan /
+                    # probes) keeps a well-formed [.., NB*bn] perm per slice
+                    perm_leaf = jnp.asarray(np.ascontiguousarray(
+                        np.broadcast_to(perm, (*lead, perm.shape[0])))
+                        if lead else perm)
+                weights = TiledBalanced(
+                    restack(tb.values), restack(tb.indices),
+                    restack(tb.counts), n_in=n_in, bn=blk.bn,
+                    perm=perm_leaf)
+                if quant != "none":
+                    weights = quantize_tiled(weights, quant)
+            else:
+                weights = BalancedSparse(vals.reshape(*lead, n_out, k),
+                                         jnp.asarray(idx).reshape(
+                                             *lead, n_out, k), n_in)
     flow = choose_dataflow(LayerSpec(name=nm, kind="fc", c_i=n_in,
                                      c_o=n_out,
                                      w_sparsity=1.0 - k / n_in))

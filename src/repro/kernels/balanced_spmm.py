@@ -114,6 +114,13 @@ def _kernel(x_ref, v_ref, i_ref, *rest, quant: str, nb_axis: int):
     o_ref[...] += acc.reshape(o_ref.shape)
 
 
+def named(kernel: str) -> dict:
+    """``pallas_call`` keywords that name a kernel: its ``name`` and the
+    ``kernel_metadata`` that the TPU custom call carries into the HLO,
+    by which `bench/hlo_scopes.py` credits the kernel's device time."""
+    return {"name": kernel, "metadata": {"kernel": kernel}}
+
+
 def _weight_operands(values, indices, scales, quant: str):
     """Kernel operands: values, indices, and the scales as a trailing-unit
     column (``[.., NB, O, 1]``: a ``(bo, 1)`` block is whole in its last
@@ -146,6 +153,7 @@ def tiled_balanced_spmm_pallas(x: Array, tb: TiledBalanced, *, bm: int = 128,
         in_specs.append(pl.BlockSpec((1, bo, 1), lambda i, j, b: (b, j, 0)))
     return pl.pallas_call(
         functools.partial(_kernel, quant=tb.quant, nb_axis=2),
+        **named("balanced_spmm_wide"),
         grid=(m // bm, o // bo, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bo), lambda i, j, b: (i, j)),
@@ -177,6 +185,7 @@ def tiled_balanced_spmm_skinny_pallas(x: Array, tb: TiledBalanced, *,
         in_specs.append(pl.BlockSpec((1, bo, 1), lambda j, b: (b, j, 0)))
     return pl.pallas_call(
         functools.partial(_kernel, quant=tb.quant, nb_axis=1),
+        **named("balanced_spmm_skinny"),
         grid=(o // bo, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((m, bo), lambda j, b: (0, j)),
@@ -214,6 +223,7 @@ def tiled_balanced_spmm_batched_pallas(x: Array, values: Array,
             pl.BlockSpec((1, 1, bo, 1), lambda g, i, j, b: (g, b, j, 0)))
     return pl.pallas_call(
         functools.partial(_kernel, quant=quant, nb_axis=3),
+        **named("balanced_spmm_batched"),
         grid=(e, m // bm, o // bo, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bm, bo), lambda g, i, j, b: (g, i, j)),

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .balanced_spmm import interpret_mode
+from .balanced_spmm import interpret_mode, named
 
 Array = jax.Array
 
@@ -73,6 +73,7 @@ def kv_cache_update_pallas(cache: Array, new: Array, pos: Array, *,
         out_shape=jax.ShapeDtypeStruct((p, s, dh), cache.dtype),
         input_output_aliases={2: 0},
         interpret=interpret_mode(interpret),
+        **named("kv_cache_update"),
     )(pos, new, cache)
     return out
 

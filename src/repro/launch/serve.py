@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..configs import ARCHS, get_config, get_smoke
 from ..core.compression import compressed_bits
 from ..engine import execute as engine_execute
@@ -380,13 +381,18 @@ def main(argv=None):
         print(f"[serve] --attn-only is inapplicable to family {cfg.family} "
               "(no separate attention projections are planned); planning "
               "the full projection family")
+    obs.reset()
     t_plan = time.monotonic()
     plan = engine_plan.plan_model(cfg, params, **plan_kwargs)
     jax.block_until_ready(plan)
     plan_build_s = time.monotonic() - t_plan
+    plan_phases_s = {name: s for name, (_, s) in obs.totals().items()
+                     if name.startswith("plan.")}
     print(f"[serve] family={cfg.family} layer plan ({len(plan.layers)} "
           f"projection groups x {cfg.n_layers} layers, built in "
-          f"{plan_build_s:.1f}s):")
+          f"{plan_build_s:.1f}s; "
+          + ", ".join(f"{k} {v:.1f}s" for k, v in plan_phases_s.items())
+          + "):")
     print(plan.summary())
     if args.tune != "off":
         deltas = plan.tune_deltas()
@@ -548,7 +554,7 @@ def main(argv=None):
         "sparse_layers": plan.sparse_layer_count,
         "degraded_mix": plan.degraded_mix(),
         "quarantined": list(plan.quarantined()),
-        "build_s": plan_build_s,
+        "build_s": plan_build_s, "phases_s": plan_phases_s,
         "parity_max_abs_diff": diff, "parity_tol": tol,
         "served_logits_max_abs_diff": bf16_diff, "engine_stats": stats,
         "tune": {"mode": args.tune, "sources": plan.tuned_mix(),

@@ -253,31 +253,34 @@ def _attn(cfg: ModelConfig, lp, h: Array, positions: Array, mesh,
         k_t = to_planes(k).astype(k_cache.dtype)            # [B*KH, s, dh]
         v_t = to_planes(v).astype(v_cache.dtype)
         pos_rep = jnp.repeat(clen, nkv)                     # [B*KH]
-        if cfg.cache_update == "scatter":
-            # row-sized indexed write: O(B*KH*s*dh) traffic instead of a
-            # full-cache rewrite (§Perf C) — the XLA twin of the Pallas
-            # `kv_cache_update` kernel; plane layout keeps it genuinely in
-            # place (no relayout around the write).
-            k_cache = kv_cache_write_chunk(k_cache, k_t, pos_rep)
-            v_cache = kv_cache_write_chunk(v_cache, v_t, pos_rep)
-        else:
-            # mask-select rewrite: elementwise over the cache, trivially
-            # partition-safe for any cache sharding (the baseline).  The
-            # one-hot einsum is exact (products with 1.0/0.0), so this and
-            # the scatter path are bitwise-identical.
-            smax = k_cache.shape[1]
-            rows = pos_rep[:, None] + jnp.arange(s)[None, :]
-            oh = rows[:, :, None] == jnp.arange(smax)[None, None, :]
-            written = oh.any(axis=1)[..., None]             # [B*KH, Smax, 1]
-            ohf = oh.astype(k_cache.dtype)
-            k_cache = jnp.where(written,
-                                jnp.einsum("pcs,pcd->psd", ohf, k_t),
-                                k_cache)
-            v_cache = jnp.where(written,
-                                jnp.einsum("pcs,pcd->psd", ohf, v_t),
-                                v_cache)
-        o = decode_attention_planes(q, k_cache.astype(_cdtype(cfg)),
-                                    v_cache.astype(_cdtype(cfg)), clen)
+        with jax.named_scope("kv_update"):
+            if cfg.cache_update == "scatter":
+                # row-sized indexed write: O(B*KH*s*dh) traffic instead of
+                # a full-cache rewrite (§Perf C) — the XLA twin of the
+                # Pallas `kv_cache_update` kernel; plane layout keeps it
+                # genuinely in place (no relayout around the write).
+                k_cache = kv_cache_write_chunk(k_cache, k_t, pos_rep)
+                v_cache = kv_cache_write_chunk(v_cache, v_t, pos_rep)
+            else:
+                # mask-select rewrite: elementwise over the cache,
+                # trivially partition-safe for any cache sharding (the
+                # baseline).  The one-hot einsum is exact (products with
+                # 1.0/0.0), so this and the scatter path are
+                # bitwise-identical.
+                smax = k_cache.shape[1]
+                rows = pos_rep[:, None] + jnp.arange(s)[None, :]
+                oh = rows[:, :, None] == jnp.arange(smax)[None, None, :]
+                written = oh.any(axis=1)[..., None]     # [B*KH, Smax, 1]
+                ohf = oh.astype(k_cache.dtype)
+                k_cache = jnp.where(written,
+                                    jnp.einsum("pcs,pcd->psd", ohf, k_t),
+                                    k_cache)
+                v_cache = jnp.where(written,
+                                    jnp.einsum("pcs,pcd->psd", ohf, v_t),
+                                    v_cache)
+        with jax.named_scope("attention"):
+            o = decode_attention_planes(q, k_cache.astype(_cdtype(cfg)),
+                                        v_cache.astype(_cdtype(cfg)), clen)
         kv_out = (k_cache, v_cache)
     else:
         q_chunk = min(cfg.q_chunk, s)
@@ -286,11 +289,21 @@ def _attn(cfg: ModelConfig, lp, h: Array, positions: Array, mesh,
             q_chunk //= 2
         while s % kv_chunk:
             kv_chunk //= 2
-        o = blocked_causal_attention(q, k, v, q_chunk=max(q_chunk, 1),
-                                     kv_chunk=max(kv_chunk, 1), mesh=mesh)
+        with jax.named_scope("attention"):
+            o = blocked_causal_attention(q, k, v, q_chunk=max(q_chunk, 1),
+                                         kv_chunk=max(kv_chunk, 1),
+                                         mesh=mesh)
         kv_out = (k, v)
     o = o.reshape(b, s, nh * dh)
     return _proj(lp, plan_layers, "wo", o, cd), kv_out
+
+
+def _lm_head(cfg: ModelConfig, params, h: Array) -> Array:
+    """Final norm and the tied-embedding logits of the last position."""
+    with jax.named_scope("lm_head"):
+        h = _norm(cfg, h, params["final_norm"])
+        return (h[:, -1].astype(jnp.float32)
+                @ params["embed"].astype(jnp.float32).T)
 
 
 def _mlp(cfg: ModelConfig, lp, h: Array, plan_layers=None) -> Array:
@@ -494,9 +507,7 @@ def build(cfg: ModelConfig, mesh=None) -> ModelBundle:
         xs = (params["blocks"], plan.layers) if plan is not None \
             else params["blocks"]
         (h,), (ks, vs) = jax.lax.scan(body_fn, (h,), xs)
-        h = _norm(cfg, h, params["final_norm"])
-        logits = (h[:, -1].astype(jnp.float32)
-                  @ params["embed"].astype(jnp.float32).T)
+        logits = _lm_head(cfg, params, h)
         return logits, {"k": ks, "v": vs}
 
     def init_cache(batch_size, max_len):
@@ -531,9 +542,7 @@ def build(cfg: ModelConfig, mesh=None) -> ModelBundle:
         if plan is not None:
             xs = xs + (plan.layers,)
         h, (ks, vs) = jax.lax.scan(body, h, xs)
-        h = _norm(cfg, h, params["final_norm"])
-        logits = (h[:, -1].astype(jnp.float32)
-                  @ params["embed"].astype(jnp.float32).T)
+        logits = _lm_head(cfg, params, h)
         return logits, {"k": ks, "v": vs}
 
     def specs():

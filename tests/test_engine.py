@@ -442,6 +442,10 @@ def test_serve_smoke_sparse_path_end_to_end():
     assert results["plan"]["engine_stats"].get("balanced_spmm", 0) > 0
     assert "ON_CHIP" in results["plan"]["mode_mix"]
     assert results["sparse"]["tokens_per_s"] > 0
+    # where start-up goes: the plan build's phases beside its total
+    phases = results["plan"]["phases_s"]
+    assert {"plan.prune", "plan.blocks", "plan.encode"} <= set(phases)
+    assert sum(phases.values()) <= results["plan"]["build_s"]
 
 
 def test_serve_moe_expert_path_end_to_end():

@@ -356,6 +356,19 @@ def test_kv_cache_update_kernel(b, s, kh, dh):
     np.testing.assert_allclose(np.asarray(got_xla), np.asarray(want))
 
 
+def test_kv_cache_update_kernel_carries_its_name():
+    """Named like the balanced-spmm kernels, so a device trace credits it
+    to the KV write.  It does not lower for Mosaic yet (its [1, dh] row
+    block breaks the (8, 128) tiling), so the name is read from the
+    jaxpr, not from compiled v5e text."""
+    from repro.kernels.kv_cache_update import kv_cache_update_pallas
+    jaxpr = jax.make_jaxpr(kv_cache_update_pallas)(
+        jnp.zeros((4, 16, 8)), jnp.zeros((4, 8)), jnp.zeros((4,), jnp.int32))
+    [eqn] = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert eqn.params["name"] == "kv_cache_update"
+    assert dict(eqn.params["metadata"]) == {"kernel": "kv_cache_update"}
+
+
 def test_kv_cache_plane_roundtrip_and_chunk_write():
     from repro.kernels.kv_cache_update import (from_planes, to_planes,
                                                kv_cache_update_xla,

@@ -6,13 +6,16 @@ Each test compiles — never runs — one raw ``pallas_call`` with
 50%-sparse KB of 88 slots) and checks the compiled program holds the Mosaic
 kernel (``tpu_custom_call``).  This is what interpret-mode tests cannot
 see: block shapes Mosaic refuses, primitives it has no lowering for, and
-VMEM overflows.
+VMEM overflows.  Each compiled kernel also carries its name in the
+``kernel_metadata`` the benchmark credits its device time by.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU library.  The persistent
 compilation cache is off around these compiles (an entry written for a
 described chip cannot be read back without one).
 """
+import re
+
 import pytest
 
 import jax
@@ -67,9 +70,10 @@ def _weights(sharding, lead, o, n, quant):
     return vals, idx, scales
 
 
-def _assert_mosaic(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_mosaic(kernel, fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r'kernel_metadata=\{\s*"kernel":"%s"\s*\}' % kernel, text)
 
 
 @pytest.mark.parametrize("quant", QUANTS)
@@ -81,8 +85,8 @@ def test_wide_kernel_compiles_for_v5e(one_chip, o, n, quant):
         tb = TiledBalanced(v, i, None, n_in=n, bn=BN, scales=s, quant=quant)
         return tiled_balanced_spmm_pallas(x, tb, bm=128, bo=128,
                                           interpret=False)
-    _assert_mosaic(f, _sds(one_chip, (128, n), jnp.bfloat16), vals, idx,
-                   scales)
+    _assert_mosaic("balanced_spmm_wide", f,
+                   _sds(one_chip, (128, n), jnp.bfloat16), vals, idx, scales)
 
 
 @pytest.mark.parametrize("quant", QUANTS)
@@ -94,8 +98,8 @@ def test_skinny_kernel_compiles_for_v5e(one_chip, o, n, quant):
         tb = TiledBalanced(v, i, None, n_in=n, bn=BN, scales=s, quant=quant)
         return tiled_balanced_spmm_skinny_pallas(x, tb, bo=128,
                                                  interpret=False)
-    _assert_mosaic(f, _sds(one_chip, (8, n), jnp.bfloat16), vals, idx,
-                   scales)
+    _assert_mosaic("balanced_spmm_skinny", f,
+                   _sds(one_chip, (8, n), jnp.bfloat16), vals, idx, scales)
 
 
 @pytest.mark.parametrize("quant", QUANTS)
@@ -107,5 +111,6 @@ def test_batched_expert_kernel_compiles_for_v5e(one_chip, quant):
         return tiled_balanced_spmm_batched_pallas(
             x, v, i, bn=BN, bm=128, bo=128, scales=s, quant=quant,
             interpret=False)
-    _assert_mosaic(f, _sds(one_chip, (e, 128, n), jnp.bfloat16), vals, idx,
+    _assert_mosaic("balanced_spmm_batched", f,
+                   _sds(one_chip, (e, 128, n), jnp.bfloat16), vals, idx,
                    scales)
