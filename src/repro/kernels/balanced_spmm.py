@@ -12,17 +12,22 @@ paper's equal-NZE-per-PE-column invariant, DESIGN.md §3).
 Grid ``(M/bm, O/bo, NB)``: each step DMAs one weight block
 ``values/indices[nb, o-block] -> [bo, KB]`` (block ``(1, bo, KB)``: the last
 two dims are a sublane multiple and the whole slot axis, which is what
-Mosaic accepts), decodes it to a dense ``[bo, bn]`` f32 tile in VMEM, and
-accumulates ``x_tile[bm, bn] @ w_tile.T`` on the MXU.  This is the
-column-combining move (Kung et al.): sparse columns packed into dense tiles
-the array consumes at full utilization.
+Mosaic accepts), decodes it to a dense, transposed ``[bn, bo]`` f32 tile in
+VMEM, and accumulates ``x_tile[bm, bn] @ w_tile[bn, bo]`` on the MXU.  This
+is the column-combining move (Kung et al.): sparse columns packed into
+dense tiles the array consumes at full utilization.
 
-The decode is an unrolled compare-select over the KB slots — slot ``k``
-adds ``where(col == idx[:, k], val[:, k], 0)`` to the tile.  Mosaic has no
-in-kernel scatter and no dynamic lane slicing, so this is the formulation
-that lowers; it costs ``KB`` VPU passes over the tile per grid step.
-Padded slots carry value 0 / index 0 and real indices are distinct within
-a block, so the decode is exact with no count masking.
+The decode is slot-major.  The ``[bo, KB]`` values and indices are
+transposed once in VMEM to ``[KB, bo]``, so slot ``k`` is a row that
+reaches the tile by a sublane broadcast (as ``[bo, 1]`` columns, every
+slot would pay a cross-lane broadcast per vreg).  The slots are then walked
+last to first as a select chain, ``w = where(rows == idx[k], val[k], w)``:
+Mosaic has no in-kernel scatter, so this is the formulation that lowers,
+one compare and one select per slot and vreg.  Padded slots carry value 0 /
+index 0 and sit after a row's real slots, so walking backwards writes every
+pad before any real entry of column 0, which then overwrites it; real
+indices are distinct within a block, so the decode is exact with no count
+masking.
 
 VMEM per step (f32): bm*bn (x) + bo*KB*2 (vals+idx) + bo*bn (decoded tile)
 + bm*bo (acc) — at bm=bo=bn=128, KB=64: ~0.26 MiB.  `ops.choose_blocks`
@@ -61,29 +66,33 @@ def _packed_kb(kb: int, quant: str) -> int:
 
 
 def _decode_tile(vals, idx, scales, quant: str, bn: int):
-    """Decode one weight block to a dense ``[bo, bn]`` f32 VMEM tile.
+    """Decode one weight block to a dense, transposed ``[bn, bo]`` f32 tile.
 
     ``vals`` is the stored encoding (f32/bf16, int8, or nibble-packed
-    uint8), ``idx`` the block-local [bo, KB] indices, ``scales`` the
-    per-row [bo, 1] block scales (None when quant == "none").  Quantized
-    values dequantize *here*, in VMEM: each tile element receives at most
-    one slot's ``q``, so scaling the decoded tile once is exactly
+    uint8) and ``idx`` the block-local indices, both ``[bo, KB]`` as
+    stored; ``scales`` the per-row ``[bo, 1]`` block scales (None when
+    quant == "none").  Each is transposed once in VMEM, so slot ``k`` is
+    row ``k`` (see the module docstring).  The backward select chain is
+    exact only while every pad (value 0, index 0) follows its row's real
+    slots, as the encoders write them.  Quantized values dequantize
+    *here*: scaling the decoded tile once is exactly
     `tile_format.dequantize_values` (the parity reference) per element.
     """
     bo, kb = idx.shape
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bo, bn), 1)
-    words = vals.astype(jnp.int32 if quant == "int4" else jnp.float32)
-    w = jnp.zeros((bo, bn), jnp.float32)
-    for k in range(kb):
+    idx_t = idx.T                                                # [KB, bo]
+    words = vals.astype(jnp.int32 if quant == "int4" else jnp.float32).T
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bn, bo), 0)
+    w = jnp.zeros((bn, bo), jnp.float32)
+    for k in reversed(range(kb)):
         if quant == "int4":
             # slot k is nibble k % 2 of byte k // 2; (n ^ 8) - 8 sign-extends
-            nib = (words[:, k // 2:k // 2 + 1] >> (4 * (k % 2))) & 0xF
+            nib = (words[k // 2:k // 2 + 1, :] >> (4 * (k % 2))) & 0xF
             v = ((nib ^ 8) - 8).astype(jnp.float32)
         else:
-            v = words[:, k:k + 1]
-        w = w + jnp.where(cols == idx[:, k:k + 1], v, 0.0)
+            v = words[k:k + 1, :]
+        w = jnp.where(rows == idx_t[k:k + 1, :], v, w)
     if scales is not None:
-        w = w * scales
+        w = w * scales.T
     return w
 
 
@@ -105,11 +114,11 @@ def _kernel(x_ref, v_ref, i_ref, *rest, quant: str, nb_axis: int):
     vals = v_ref[...].reshape(v_ref.shape[-2:])                 # [bo, KBp]
     idx = i_ref[...].reshape(i_ref.shape[-2:])                  # [bo, KB]
     scales = None if s_ref is None else s_ref[...].reshape(s_ref.shape[-2:])
-    w_tile = _decode_tile(vals, idx, scales, quant, x.shape[1])
+    w_tile = _decode_tile(vals, idx, scales, quant, x.shape[1])  # [bn, bo]
     # f32 activations get an f32 product (bf16 ones are one exact MXU pass)
     precision = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
     acc = jax.lax.dot_general(x, w_tile.astype(x.dtype),
-                              (((1,), (1,)), ((), ())), precision=precision,
+                              (((1,), (0,)), ((), ())), precision=precision,
                               preferred_element_type=jnp.float32)
     o_ref[...] += acc.reshape(o_ref.shape)
 

@@ -235,6 +235,94 @@ def test_tiled_spmm_batched_matches_per_group():
                                rtol=1e-4, atol=1e-4)
 
 
+def _exact_case(layout: str, seed: int, o: int, n: int, bn: int, k: int):
+    """A flat balanced weight encoded to tiles for the exact-decode tests.
+
+    ``pruned``: magnitude-pruned random weights at the measured KB.
+    ``col0-pads`` / ``kb-odd``: every row holds local column 0 of every
+    block, beside padded slots (KB 16, and 11: no sublane multiple)."""
+    from repro.kernels.tile_format import encode_tiled
+    if layout == "pruned":
+        sp = to_balanced_sparse(rand(30 + seed, (o, n), jnp.float32), k=k)
+        return encode_tiled(sp.values, sp.indices, n, bn=bn)
+    rng = np.random.default_rng(seed)
+    firsts = np.arange(0, n, bn)
+    rest = np.setdiff1d(np.arange(n), firsts)
+    idx = np.sort(np.stack([np.concatenate(
+        [firsts, rng.choice(rest, k - firsts.size, replace=False)])
+        for _ in range(o)]), axis=1).astype(np.int32)
+    vals = rng.standard_normal((o, k)).astype(np.float32)
+    kb = {"col0-pads": 16, "kb-odd": 11}[layout]
+    return encode_tiled(jnp.asarray(vals), idx, n, bn=bn, kb=kb)
+
+
+@pytest.mark.parametrize("layout", ["pruned", "col0-pads", "kb-odd"])
+@pytest.mark.parametrize("quant", ["none", "int8", "int4"])
+@pytest.mark.parametrize("kernel", ["wide", "skinny", "batched"])
+def test_kernel_decode_equals_tiled_to_dense(kernel, quant, layout):
+    """Each kernel's in-VMEM decode is exactly the reference densify.
+    Fed the identity, a kernel returns W.T through products ``w * 1`` and
+    sums ``w + 0`` alone, so its output equals `tiled_to_dense` (which
+    dequantizes first) bit for bit."""
+    from repro.kernels.balanced_spmm import (
+        tiled_balanced_spmm_batched_pallas, tiled_balanced_spmm_pallas,
+        tiled_balanced_spmm_skinny_pallas)
+    from repro.kernels.tile_format import (TiledBalanced, quantize_tiled,
+                                           tiled_to_dense)
+    o, n, bn, k = 16, 32, 16, 10
+    groups = [_exact_case(layout, g, o, n, bn, k)
+              for g in range(2 if kernel == "batched" else 1)]
+    kb = max(t.kb for t in groups)
+    assert all(t.kb == kb for t in groups)
+    stack = TiledBalanced(*(jnp.stack([getattr(t, f) for t in groups])
+                            for f in ("values", "indices", "counts")),
+                          n_in=n, bn=bn)
+    qt = quantize_tiled(stack, quant)
+    lanes = [TiledBalanced(qt.values[g], qt.indices[g], qt.counts[g],
+                           n_in=n, bn=bn, quant=quant,
+                           scales=None if qt.scales is None
+                           else qt.scales[g]) for g in range(len(groups))]
+    eye = jnp.eye(n, dtype=jnp.float32)
+    if kernel == "wide":
+        got = [tiled_balanced_spmm_pallas(eye, lanes[0], bm=8, bo=8)]
+    elif kernel == "skinny":
+        got = [jnp.concatenate([tiled_balanced_spmm_skinny_pallas(
+            eye[r:r + 8], lanes[0], bo=8) for r in range(0, n, 8)])]
+    else:
+        got = tiled_balanced_spmm_batched_pallas(
+            jnp.stack([eye] * len(groups)), qt.values, qt.indices, bn=bn,
+            bm=8, bo=8, scales=qt.scales, quant=quant)
+    if layout != "pruned":
+        assert bool(jnp.all(lanes[0].counts < kb)), "needs pad slots"
+    for g, lane in enumerate(lanes):
+        np.testing.assert_array_equal(np.asarray(got[g]).T,
+                                      np.asarray(tiled_to_dense(lane)))
+
+
+@pytest.mark.parametrize("path", ["numpy", "traced", "int8", "int4"])
+def test_encoders_zero_every_pad_slot(path):
+    """Every slot at or past its block's count holds value 0 and index 0:
+    the kernels' backward select chain relies on it (a pad written after
+    a real entry of local column 0 would erase it)."""
+    from repro.kernels.tile_format import (encode_tiled, quantize_tiled,
+                                           unpack_int4)
+    o, n, bn, k, kb = 24, 100, 32, 30, 24
+    sp = to_balanced_sparse(rand(40, (o, n), jnp.float32), k=k)
+    if path == "traced":
+        tb = jax.jit(lambda v, i: encode_tiled(v, i, n, bn=bn, kb=kb))(
+            sp.values, sp.indices)
+    else:
+        tb = encode_tiled(sp.values, sp.indices, n, bn=bn, kb=kb)
+    vals = tb.values
+    if path in ("int8", "int4"):
+        tb = quantize_tiled(tb, path)
+        vals = unpack_int4(tb.values, kb) if path == "int4" else tb.values
+    pad = np.arange(kb)[None, None, :] >= np.asarray(tb.counts)[..., None]
+    assert pad.any() and not pad.all()
+    assert not np.asarray(vals)[pad].any()
+    assert not np.asarray(tb.indices)[pad].any()
+
+
 def test_choose_blocks_respects_vmem_budget():
     c = ops.choose_blocks(4096, 4096, 8192, 4096, itemsize=4,
                           vmem_budget=1 << 20)

@@ -12,8 +12,11 @@ VMEM overflows.  Each compiled kernel also carries its name in the
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU library.  The persistent
 compilation cache is off around these compiles (an entry written for a
-described chip cannot be read back without one).
+described chip cannot be read back without one).  Mosaic dumps each
+kernel's lowering into a module temp directory, from which one test reads
+the skinny kernel's LLO.
 """
+import os
 import re
 
 import pytest
@@ -33,9 +36,18 @@ QUANTS = ["none", "int8", "int4"]
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def mosaic_dump(tmp_path_factory):
+    return tmp_path_factory.mktemp("mosaic")
+
+
+@pytest.fixture(scope="module")
+def one_chip(mosaic_dump):
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")     # no compiler logs in /tmp
+        # read once, where the TPU library loads: before the topology
+        mp.setenv("LIBTPU_INIT_ARGS", " ".join(filter(None, [
+            os.environ.get("LIBTPU_INIT_ARGS"),
+            f"--xla_mosaic_dump_to={mosaic_dump}"])))
         from jax.experimental import topologies
         from jax.experimental.compilation_cache import compilation_cache
         from jax.sharding import SingleDeviceSharding
@@ -114,3 +126,29 @@ def test_batched_expert_kernel_compiles_for_v5e(one_chip, quant):
     _assert_mosaic("balanced_spmm_batched", f,
                    _sds(one_chip, (e, 128, n), jnp.bfloat16), vals, idx,
                    scales)
+
+
+def test_skinny_kernel_decode_has_no_cross_lane_permutes(one_chip,
+                                                         mosaic_dump):
+    """The skinny kernel's LLO (olmo-1b's MLP up shape): one compare per
+    slot and vreg of the [128, 128] f32 tile, KB x 16, and no cross-lane
+    permute: each slot is a row that reaches the tile by a sublane
+    broadcast.  Every skinny dump of this module is read, whatever quant
+    its compile used."""
+    o, n = 8192, 2048
+    vals, idx, _ = _weights(one_chip, (), o, n, "none")
+
+    def f(x, v, i):
+        tb = TiledBalanced(v, i, None, n_in=n, bn=BN)
+        return tiled_balanced_spmm_skinny_pallas(x, tb, bo=128,
+                                                 interpret=False)
+    _assert_mosaic("balanced_spmm_skinny", f,
+                   _sds(one_chip, (8, n), jnp.bfloat16), vals, idx)
+    dumps = sorted(mosaic_dump.glob("*balanced_spmm_skinny*finalize-llo*"))
+    if not dumps:
+        pytest.skip("Mosaic wrote no LLO dump (the TPU library was loaded "
+                    "before this module set its flags)")
+    for path in dumps:
+        text = path.read_text()
+        assert "llo.vperm" not in text, path.name
+        assert len(re.findall(r"\bllo\.vcmp\.", text)) == KB * 16, path.name
