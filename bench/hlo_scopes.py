@@ -14,9 +14,11 @@ bucket:
 
 * a kernel whose ``kernel_metadata`` names one of ``KERNELS`` goes to that
   kernel's bucket, before any scope is looked at;
-* any other instruction goes to the one program scope (``SCOPES``) found
-  among the ``op_name`` path components of it and, for a fusion, of every
+* any other instruction goes to the one program scope found among the
+  ``op_name`` path components of it and, for a fusion, of every
   instruction it fuses; none, or more than one, goes to ``unattributed``.
+  The scopes are the cell's family's (``SCOPES`` of `bench.families`);
+  the kernel names are one table here, shared by every family.
 
 The op self times of the window (`bench.trace.Summary.op_s`) are summed by
 bucket, so each op counts once and the buckets sum to the window's total.
@@ -25,19 +27,18 @@ nothing.
 """
 from __future__ import annotations
 
+import collections
 import glob
 import json
 import os
 
-from bench import trace
+from bench import families, trace
 
-SCOPES = ("kv_update", "attention", "lm_head")
 KERNELS = {"balanced_spmm_wide": "balanced_spmm",
            "balanced_spmm_skinny": "balanced_spmm",
            "balanced_spmm_batched": "balanced_spmm",
            "kv_cache_update": "kv_update"}
 UNATTRIBUTED = "unattributed"
-BUCKETS = ("balanced_spmm", *SCOPES, UNATTRIBUTED)
 MODULES_LINE = "XLA Modules"
 METADATA_PLANE = "/host:metadata"
 HLO_STAT = "Hlo Proto"
@@ -150,9 +151,10 @@ def _kernel_name(frontend) -> str | None:
     return None
 
 
-def attribute(hlo_proto) -> dict[str, str]:
+def attribute(hlo_proto, scopes) -> dict[str, str]:
     """``{instruction name: bucket}`` for the top-level instructions (those
-    no fusion holds) of one ``HloProto``."""
+    no fusion holds) of one ``HloProto``, with ``scopes`` the program
+    scopes that get a bucket of their own."""
     module = dict(fields(memoryview(hlo_proto)))[HLOPROTO_MODULE]
     # computation id -> [(name, opcode, op_name, called ids, kernel name)]
     comps = {}
@@ -204,28 +206,30 @@ def attribute(hlo_proto) -> dict[str, str]:
             body = list(reach(instr))
             kernels = {KERNELS[i[4]] for i in body if i[4] in KERNELS}
             found = kernels or {s for i in body for s in i[2].split("/")
-                                if s in SCOPES}
+                                if s in scopes}
             out[instr[0]] = found.pop() if len(found) == 1 else UNATTRIBUTED
     return out
 
 
-def split(op_s: dict, attribution: dict) -> dict[str, float]:
-    """Sum op self seconds (keyed by `bench.trace.short_name`) by bucket;
-    an op the attribution does not hold is unattributed."""
-    out = dict.fromkeys(BUCKETS, 0.0)
+def split(op_s: dict, attribution: dict, scopes) -> dict[str, float]:
+    """Sum op self seconds (keyed by `bench.trace.short_name`) by bucket:
+    the kernels', then ``scopes``, then ``unattributed``, which also takes
+    an op the attribution does not hold."""
+    out = dict.fromkeys([*KERNELS.values(), *scopes, UNATTRIBUTED], 0.0)
     for op, s in op_s.items():
         name = op.split(" = ", 1)[0].lstrip("%")
         out[attribution.get(name, UNATTRIBUTED)] += s
     return out
 
 
-def window_modules(planes) -> set[str]:
-    """Names of the modules whose runs start inside ``bench.window``."""
+def window_modules(planes) -> collections.Counter:
+    """Runs, by module name, that start inside ``bench.window``."""
     win = [e for e in trace.host_spans(planes)
            if e.name == trace.WINDOW_SPAN][0]
-    return {e.name for p in trace.device_planes(planes)
-            for e in p.lines.get(MODULES_LINE, ())
-            if win.start_ns <= e.start_ns < win.end_ns}
+    return collections.Counter(
+        e.name for p in trace.device_planes(planes)
+        for e in p.lines.get(MODULES_LINE, ())
+        if win.start_ns <= e.start_ns < win.end_ns)
 
 
 def newest_xplane(path: str) -> str | None:
@@ -235,25 +239,29 @@ def newest_xplane(path: str) -> str | None:
 
 
 def window_split(ctx) -> dict[str, float] | None:
-    """Seconds of the traced window's device ops by bucket, or None where
-    the window ran other than one module or the module names nothing."""
+    """Seconds of the traced window's device ops by bucket, with the scopes
+    of the cell's family, attributed by the module the window ran most:
+    the step (a cache rewind runs once a pass, and its ops go to
+    ``unattributed`` unless a step instruction has the same name).  None
+    where no module ran or that module names nothing."""
     from bench.run import TRACE_DIR
     if ctx.summary is None:
         return None
     path = newest_xplane(str(TRACE_DIR))
     if path is None:
         return None
-    modules = window_modules(trace.load(path))
-    if len(modules) != 1:
+    modules = window_modules(trace.load(path)).most_common(1)
+    if not modules:
         return None
     with open(path, "rb") as f:
-        protos = hlo_protos(f.read(), modules)
+        protos = hlo_protos(f.read(), {modules[0][0]})
     if len(protos) != 1:
         return None
-    attribution = attribute(next(iter(protos.values())))
+    scopes = families.load(ctx.spec["model"]["family"]).SCOPES
+    attribution = attribute(next(iter(protos.values())), scopes)
     if set(attribution.values()) <= {UNATTRIBUTED}:
         return None
-    return split(ctx.summary.op_s, attribution)
+    return split(ctx.summary.op_s, attribution, scopes)
 
 
 def step_ms(ctx, bucket: str) -> float | None:
@@ -261,6 +269,7 @@ def step_ms(ctx, bucket: str) -> float | None:
     if not hasattr(ctx, "_window_split"):
         ctx._window_split = window_split(ctx)
     steps = ctx.counters.get("steps")
-    if ctx._window_split is None or not steps:
+    if ctx._window_split is None or not steps \
+            or bucket not in ctx._window_split:
         return None
     return 1e3 * ctx._window_split[bucket] / steps

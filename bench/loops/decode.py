@@ -7,11 +7,14 @@ and compiles the decode step.  The window then runs
 token and the cache length fed back on the device, one step in flight
 while the host waits for the one before; nothing crosses from the host
 to the device inside the window.  Should the cache fill, every row starts
-over from its prompt (``cache_len`` goes back to the prompt length; the
-rows past it are masked and then overwritten), so the shapes never change
-and nothing compiles.  That holds for caches whose every leaf has a
-sequence axis, as the dense family's K/V have.  The check reads the rows
-of the first pass, the longest.
+over from its prompt, so the shapes never change and nothing compiles:
+``cache_len`` goes back to the prompt length (the rows past it of a leaf
+with a sequence axis, as K/V, are masked and then overwritten), and every
+leaf with no sequence axis (a recurrent state, as an SSM's) gets back the
+value it had after the prefill, kept in set-up for that and put back by
+a function compiled in set-up.  The dense family's cache has no such leaf,
+so it keeps no copy.  The check reads the rows of the first pass, the
+longest.
 """
 from __future__ import annotations
 
@@ -30,6 +33,37 @@ def merge_prefill(cache, prefill_cache):
     return jax.tree.map(
         lambda z, pf: jax.lax.dynamic_update_slice(
             z, pf.astype(z.dtype), (0,) * z.ndim), cache, prefill_cache)
+
+
+def recurrent_leaves(init_cache, batch: int, capacity: int) -> tuple:
+    """Positions, in the flattened cache, of the leaves with no sequence
+    axis: those whose shapes are the same at ``capacity`` rows and at one
+    more."""
+    a, b = (jax.tree.leaves(jax.eval_shape(lambda: init_cache(batch, n)))
+            for n in (capacity, capacity + 1))
+    return tuple(i for i, (x, y) in enumerate(zip(a, b))
+                 if x.shape == y.shape)
+
+
+def rewinder(cache, which: tuple, donate: bool = False):
+    """A copy of the leaves ``which`` of ``cache`` as they are now, and
+    ``restore(cache, copy)``, compiled here, which puts them back into a
+    later cache (donated, with ``donate``) and leaves the other leaves
+    alone.  ``(None, None)`` where ``which`` is empty."""
+    if not which:
+        return None, None
+    tree = jax.tree.structure(cache)
+    saved = jax.jit(lambda ls: [jnp.copy(x) for x in ls])(
+        [jax.tree.leaves(cache)[i] for i in which])
+
+    def restore(c, kept):
+        leaves = jax.tree.leaves(c)
+        for i, x in zip(which, kept):
+            leaves[i] = jnp.copy(x)     # the copy is kept for the next pass
+        return jax.tree.unflatten(tree, leaves)
+    restore = jax.jit(restore, donate_argnums=(0,) if donate else ()).lower(
+        cache, saved).compile()
+    return saved, restore
 
 
 def build(ctx, params):
@@ -72,17 +106,19 @@ def run(ctx) -> dict:
     cache = jax.jit(lambda pc: merge_prefill(bundle.init_cache(bsz, cap),
                                              pc))(pcache)
     del pcache
+    on_chip = jax.default_backend() != "cpu"     # the CPU cannot donate
+    kept, restore = rewinder(
+        cache, recurrent_leaves(bundle.init_cache, bsz, cap), on_chip)
 
     def step(p, tok, clen, c):
         lg, c = bundle.decode_step(
             p, {"tokens": tok[:, None],
                 "cache_len": jnp.full((bsz,), clen, jnp.int32)}, c)
         return jnp.argmax(lg, -1).astype(jnp.int32), clen + 1, c
-    donate = (3,) if jax.default_backend() != "cpu" else ()
     prompt_end = jax.device_put(np.int32(plen))
-    step = jax.jit(step, donate_argnums=donate).lower(
+    step = jax.jit(step, donate_argnums=(3,) if on_chip else ()).lower(
         sp, first, prompt_end, cache).compile()
-    jax.block_until_ready((first, cache, prompt_end))
+    jax.block_until_ready((first, cache, prompt_end, kept))
 
     per_tok = work.token_flops(spec, np.arange(cap + 1))
     spmm_step = work.spmm_forward(spec, bsz).least_s(ctx.peak)
@@ -95,6 +131,9 @@ def run(ctx) -> dict:
         while True:
             if n == cap:
                 clen, n, passes = prompt_end, plen, passes + 1
+                if restore is not None:
+                    with ctx.span("bench.rewind"):
+                        cache = restore(cache, kept)
             with ctx.span("bench.decode_step"):
                 nxt, clen, cache = step(sp, tok, clen, cache)
             if passes == 1:
@@ -127,7 +166,8 @@ def run(ctx) -> dict:
           f"{longest[0]!r} s (step {longest[1]})", flush=True)
 
     toks = np.stack([np.asarray(t) for t in served], 1)
-    del sp, cache, step, tok, nxt, prev, first, clen, prompt_end, served
+    del sp, cache, step, tok, nxt, prev, first, clen, prompt_end, served, \
+        kept, restore
     ctx.counters["ref_s"] = check(ctx, prompts, toks)
     return {"decode_tok_s": steps * bsz / window_s,
             "peak_hbm_gb": ctx.counters["memory_peak_bytes"] / 1e9}

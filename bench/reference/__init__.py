@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import families
 from ..weights import make_params
 from .common import gaps
 
@@ -45,13 +46,15 @@ def compare(spec: dict, seed: int, requests: list[Served],
     """Run the reference (and the fp8 control, if asked) over
     ``requests``; returns the widest served-token gap, the count of
     tokens checked and, with ``control``, the control's widest gap."""
+    family = spec["model"]["family"]
     params = make_params(spec["model"], seed)
-    fn = hidden_fn(spec["model"]["family"])
+    fn = hidden_fn(family)
+    logits_head = families.load(family).head(params)
     t_max = max(len(r.tokens) for r in requests)
     t_pad = -(-t_max // T_BUCKET) * T_BUCKET
     run = jax.jit(lambda p, toks, lowp: fn(spec, p, toks, lowp),
                   static_argnums=2)
-    head = jax.jit(gaps)
+    gaps_of = jax.jit(gaps)
     served_gap, control_gap, n = [], [], 0
     with jax.default_matmul_precision("highest"):
         for i in range(0, len(requests), ROWS):
@@ -64,8 +67,8 @@ def compare(spec: dict, seed: int, requests: list[Served],
                 else h_ref
             for j, r in enumerate(chunk):
                 pos = jnp.arange(r.start, r.start + len(r.served))
-                sg, cg = head(h_ref[j][pos], h_ctl[j][pos], params["embed"],
-                              jnp.asarray(r.served, jnp.int32))
+                sg, cg = gaps_of(h_ref[j][pos], h_ctl[j][pos], logits_head,
+                                 jnp.asarray(r.served, jnp.int32))
                 served_gap.append(np.asarray(sg))
                 control_gap.append(np.asarray(cg))
                 n += len(r.served)

@@ -9,6 +9,14 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 # widths (the fp8 control reads 0.07-0.15 here, 0.59-0.72 on the chip
 # against a limit of 0.16), so the smoke runs' limit is scaled alike.
 SMOKE_LIMIT = 0.05
+# Every configuration of the benchmark, for the tests that cover each.
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONFIGS = [c["name"] for c in BENCH["configs"]]
+
+
+def cell_of(arch: str) -> str:
+    """The first cell that runs configuration ``arch``."""
+    return next(w["name"] for w in BENCH["workloads"] if w["config"] == arch)
 
 
 def smoke_spec(arch: str, dtype: str = "float32") -> dict:

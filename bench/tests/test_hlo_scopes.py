@@ -7,7 +7,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import hlo_scopes
+from bench import families, hlo_scopes
+
+SCOPES = families.load("dense").SCOPES
 
 
 def _varint(x: int) -> bytes:
@@ -95,7 +97,7 @@ def test_hlo_protos_picks_modules_by_event_name():
 
 
 def test_each_top_level_instruction_goes_to_one_bucket():
-    att = hlo_scopes.attribute(MODULE)
+    att = hlo_scopes.attribute(MODULE, SCOPES)
     assert att == {
         "spmm.1": "balanced_spmm",            # by kernel name
         "kv.1": "kv_update",                  # kernel name before scope
@@ -109,14 +111,14 @@ def test_each_top_level_instruction_goes_to_one_bucket():
 
 
 def test_split_counts_every_op_once():
-    att = hlo_scopes.attribute(MODULE)
+    att = hlo_scopes.attribute(MODULE, SCOPES)
     op_s = {"%spmm.1 = f32[8,2048] custom-call": 3.0,
             "%write_fusion = bf16[16,128,2048,128] fusion": 0.5,
             "%mixed_fusion = f32[128,2048] fusion": 0.25,
             "%head = f32[8,50304] dot": 0.125,
             "%while.4": 0.0625,                  # not in the attribution
             "%nested_fusion = f32[8] fusion": 1.0}
-    out = hlo_scopes.split(op_s, att)
+    out = hlo_scopes.split(op_s, att, SCOPES)
     assert out == {"balanced_spmm": 3.0, "kv_update": 0.5,
                    "attention": 1.0, "lm_head": 0.125,
                    "unattributed": 0.3125}
@@ -130,7 +132,8 @@ def test_unnamed_program_reads_nothing(tmp_path):
         b"attention", b"attentiom").replace(b"lm_head", b"lm_heed").replace(
         b"balanced_spmm_skinny", b"balanced_spmm_skinnz").replace(
         b"kv_cache_update", b"kv_cache_updatf")
-    assert set(hlo_scopes.attribute(unnamed).values()) == {"unattributed"}
+    assert set(hlo_scopes.attribute(unnamed, SCOPES).values()) == {
+        "unattributed"}
 
     class Ctx:
         summary = None
@@ -158,7 +161,7 @@ def test_cpu_profile_of_a_decode_step_puts_an_op_in_each_scope(tmp_path):
         protos = hlo_scopes.hlo_protos(f.read())
     names = [n for n in protos if n.startswith("jit_decode_step(")]
     assert len(names) == 1, sorted(protos)
-    buckets = set(hlo_scopes.attribute(protos[names[0]]).values())
+    buckets = set(hlo_scopes.attribute(protos[names[0]], SCOPES).values())
     assert {"kv_update", "attention", "lm_head"} <= buckets
 
 

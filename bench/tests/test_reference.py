@@ -10,11 +10,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference
+from bench import families, reference
 from bench.reference import common
 from bench.weights import make_params
 
-from .smoke import smoke_spec
+from .smoke import CONFIGS, smoke_spec
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SEED = 2**33 + 17
@@ -31,7 +31,7 @@ def program(spec):
     return build_model(dense), masked_dense_params(params, plan), plan
 
 
-@pytest.mark.parametrize("arch", ["olmo-1b"])
+@pytest.mark.parametrize("arch", CONFIGS)
 def test_reference_matches_masked_dense_prefill_and_decode(arch):
     spec = smoke_spec(arch)
     bundle, params, _ = program(spec)
@@ -54,10 +54,11 @@ def test_reference_matches_masked_dense_prefill_and_decode(arch):
             got.append(lg)
         got = np.stack([np.asarray(g) for g in got], 1)
         fn = reference.hidden_fn(spec["model"]["family"])
-        h = fn(spec, make_params(spec["model"], SEED),
-               jnp.asarray(toks[:, :plen + steps - 1]), False)
-        ref = np.asarray(h[:, plen - 1:] @ make_params(
-            spec["model"], SEED)["embed"].T)
+        ref_params = make_params(spec["model"], SEED)
+        h = fn(spec, ref_params, jnp.asarray(toks[:, :plen + steps - 1]),
+               False)
+        head = families.load(spec["model"]["family"]).head(ref_params)
+        ref = np.asarray(h[:, plen - 1:] @ head.T)
     # prefill's logits come from f32 activations: summation order only
     np.testing.assert_allclose(got[:, 0], ref[:, 0], atol=2e-4, rtol=0)
     # decode reads K/V back from the cache, which holds them in bf16
@@ -65,7 +66,7 @@ def test_reference_matches_masked_dense_prefill_and_decode(arch):
     assert np.abs(got - ref).max() > 0
 
 
-@pytest.mark.parametrize("arch", ["olmo-1b"])
+@pytest.mark.parametrize("arch", CONFIGS)
 def test_reference_prunes_as_the_plan_does(arch):
     spec = smoke_spec(arch)
     _, _, plan = program(spec)
@@ -98,7 +99,7 @@ def test_fp8_control_rounds_harder_than_bf16():
     assert e8 > 4 * e16
 
 
-@pytest.mark.parametrize("arch", ["olmo-1b"])
+@pytest.mark.parametrize("arch", CONFIGS)
 def test_seed_changes_the_values_not_the_pattern(arch):
     spec = smoke_spec(arch)
     a = make_params(spec["model"], 5)

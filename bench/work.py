@@ -6,8 +6,9 @@ sparsity alone, never from how the program lays the weights out.
   per nonzero.  Its required bytes are the nonzero values at the weight
   dtype plus the activations in and out at the compute dtype.
 * Dense matmuls cost 2 FLOPs per weight per row.
-* Attention at context ``c`` costs ``4 * c * H * dh`` per token and
-  layer (scores and the weighted sum).
+* Attention at context ``c`` costs ``c`` times the family's
+  ``attention_flops`` per token (`bench.families`; for the dense family
+  ``4 * H * dh`` in every layer: scores and the weighted sum).
 
 Elementwise work (norms, activations, rotary, convs) is left out: it is
 bounded by the matmuls at these widths.
@@ -19,6 +20,8 @@ import json
 import pathlib
 
 import numpy as np
+
+from bench import families
 
 PEAKS_FILE = pathlib.Path(__file__).resolve().parent / "peaks.json"
 
@@ -39,17 +42,6 @@ def keep(n_in: int, sparsity: float) -> int:
     return max(1, min(n_in, int(round(n_in * (1.0 - sparsity)))))
 
 
-def projections(m: dict) -> dict:
-    """name -> (n_in, n_out, copies per forward) of every weight matrix."""
-    d, f = m["d_model"], m["d_ff"]
-    qd, kd = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
-    attn = {"wq": (d, qd), "wk": (d, kd), "wv": (d, kd), "wo": (qd, d)}
-    mlp = {"w_gate": (d, f), "w_up": (d, f), "w_down": (f, d)}
-    if m["family"] != "dense":
-        raise ValueError(f"no work model for family {m['family']!r}")
-    return {k: (*v, m["n_layers"]) for k, v in {**attn, **mlp}.items()}
-
-
 @dataclasses.dataclass(frozen=True)
 class Work:
     flops: float
@@ -68,7 +60,7 @@ def spmm_forward(spec: dict, rows: int) -> Work:
     m = spec["model"]
     wb = _BYTES[m["param_dtype"]]
     ab = _BYTES[m["compute_dtype"]]
-    shapes = projections(m)
+    shapes = families.load(m["family"]).projections(m)
     flops = byts = 0.0
     for name in spec["planned"]:
         n_in, n_out, copies = shapes[name]
@@ -82,11 +74,12 @@ def token_flops(spec: dict, context) -> np.ndarray:
     """Required FLOPs of one token at each context length in ``context``
     (the positions it attends over, itself included)."""
     m = spec["model"]
+    family = families.load(m["family"])
     planned = set(spec["planned"])
     per = 0.0
-    for name, (n_in, n_out, copies) in projections(m).items():
+    for name, (n_in, n_out, copies) in family.projections(m).items():
         k = keep(n_in, m["w_sparsity"]) if name in planned else n_in
         per += copies * 2.0 * n_out * k
     per += 2.0 * m["vocab_size"] * m["d_model"]               # LM head
-    attn = 4.0 * m["n_heads"] * m["head_dim"] * m["n_layers"]
-    return per + attn * np.asarray(context, np.float64)
+    return per + family.attention_flops(m) \
+        * np.asarray(context, np.float64)
